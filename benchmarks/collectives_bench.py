@@ -19,8 +19,9 @@ import json, time
 import jax, jax.numpy as jnp
 from repro.core import hierarchy as h
 from repro.launch import hlo_analysis as H
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 out = {}
 for mb in (1, 8):
     x = jnp.ones((1024 * mb, 128), jnp.float32)   # 0.5/4 MiB per shard

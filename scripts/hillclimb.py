@@ -10,10 +10,8 @@ import json
 import sys
 from pathlib import Path
 
-import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-
 sys.path.insert(0, "src")
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.launch.dryrun_cell import lower_cell  # noqa: E402
 
 OUT = Path("artifacts/perf")
@@ -155,4 +153,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

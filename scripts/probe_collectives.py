@@ -6,10 +6,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import sys
 from collections import defaultdict
 
-import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-
 sys.path.insert(0, "src")
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config
 from repro.launch import hlo_analysis as H
 from repro.launch.dryrun_cell import (TRAIN_MICROBATCHES, _lower_and_compile,
@@ -23,6 +21,7 @@ from repro.runtime import train as train_rt
 from repro.sharding.partition import use_rules
 from repro.sharding.profiles import make_rules
 
+enable_compile_cache()
 arch = sys.argv[1] if len(sys.argv) > 1 else "olmoe-1b-7b"
 fsdp = "--no-fsdp" not in sys.argv
 

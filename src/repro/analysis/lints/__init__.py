@@ -29,10 +29,10 @@ Shipped rules:
     RNG state make event streams host-dependent; modeled clocks and
     explicitly-seeded generators do not.
 ``compat-imports``
-    The jax surfaces that drifted across 0.4.x vs >=0.6 (``shard_map``
-    kwargs, ``set_mesh``/``use_mesh``, pallas compiler params,
-    ``Compiled.cost_analysis()`` shape) must be reached through
-    ``repro.core.compat``, never imported from jax directly.
+    The jax surfaces the repo wraps (``shard_map`` with its
+    ``manual_axes`` spelling, ``Compiled.cost_analysis()``) must be
+    reached through ``repro.core.compat``, never imported from jax
+    directly.
 ``no-mutable-default``
     No mutable literals (list/dict/set displays or comprehensions) as
     function-parameter or dataclass-field defaults — the shared-
@@ -223,11 +223,10 @@ class NoWallclock(Rule):
 
 class CompatImports(Rule):
     name = "compat-imports"
-    description = ("version-drifted jax surfaces must be reached via "
+    description = ("jax surfaces the repo adapts must be reached via "
                    "repro.core.compat")
 
-    _DRIFTED_NAMES = {"shard_map", "set_mesh", "use_mesh",
-                      "CompilerParams", "TPUCompilerParams"}
+    _DRIFTED_NAMES = {"shard_map"}
     # receivers sanctioned to expose the drifted call shape
     _OK_RECEIVERS = {"compat"}
 
@@ -242,8 +241,8 @@ class CompatImports(Rule):
                     if alias.name in self._DRIFTED_NAMES:
                         yield node.lineno, (
                             f"'from {node.module} import {alias.name}' — "
-                            f"this surface drifted across jax versions; "
-                            f"import it from repro.core.compat")
+                            f"the repo wraps this surface; import it from "
+                            f"repro.core.compat")
             elif isinstance(node, ast.Call):
                 dotted = _call_name(node.func)
                 if dotted is None:
@@ -252,14 +251,9 @@ class CompatImports(Rule):
                 if tail == "cost_analysis" and head \
                         and head not in self._OK_RECEIVERS:
                     yield node.lineno, (
-                        f"{dotted}() — Compiled.cost_analysis() changed "
-                        f"shape across jax versions; call "
-                        f"repro.core.compat.cost_analysis(compiled)")
-                elif tail in ("CompilerParams", "TPUCompilerParams") \
-                        and head.split(".")[0] not in self._OK_RECEIVERS:
-                    yield node.lineno, (
-                        f"{dotted}() — pallas compiler params drifted; "
-                        f"use repro.core.compat.tpu_compiler_params()")
+                        f"{dotted}() — call "
+                        f"repro.core.compat.cost_analysis(compiled), "
+                        f"which pins the record's type")
 
 
 class NoMutableDefault(Rule):

@@ -1,82 +1,30 @@
-"""jax version-compatibility shims.
+"""Adapters over the jax API surfaces whose call shape this repo wraps.
 
-The codebase targets the current jax API (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.set_mesh``, ``pltpu.CompilerParams``)
-but must also run on jax 0.4.x containers (``jax.experimental.shard_map``
-with ``auto``/``check_rep``, context-manager ``Mesh``,
-``pltpu.TPUCompilerParams``).  Everything version-sensitive funnels
-through here.
+The repo targets one jax (0.9); nothing here branches on its version.
+``shard_map`` adds the repo's ``manual_axes`` spelling (axes handled by
+the body; the rest stay auto / GSPMD), and ``cost_analysis`` pins the
+return type the dry-run records are built from.  The ``compat-imports``
+lint keeps callers on these two.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Iterable, Optional
 
 import jax
 
-try:  # jax >= 0.6: top-level shard_map with axis_names/check_vma
-    from jax import shard_map as _new_shard_map
-    _OLD_API = False
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-    _OLD_API = True
-
-# Old XLA hard-CHECKs (IsManualSubgroup) when buffer donation meets a
-# partially-manual shard_map; callers gate donation on this.
-IS_OLD_JAX = _OLD_API
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = True,
               manual_axes: Optional[Iterable[str]] = None):
-    """shard_map across jax versions.
-
-    ``manual_axes``: axes handled manually by ``f`` (the rest stay auto /
-    GSPMD).  None = all mesh axes manual.  ``check`` maps to
-    ``check_vma`` (new) / ``check_rep`` (old).
-    """
-    if not _OLD_API:
-        kw = {}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check, **kw)
+    """``jax.shard_map``.  ``manual_axes``: axes handled manually by ``f``
+    (None = all mesh axes manual).  ``check`` maps to ``check_vma``."""
     kw = {}
     if manual_axes is not None:
-        kw["auto"] = frozenset(set(mesh.axis_names) - set(manual_axes))
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check, **kw)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh`` when available; on 0.4.x the Mesh object itself is
-    the context manager that scopes GSPMD lowering."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(type(mesh), "__enter__"):
-        return mesh
-    return contextlib.nullcontext()  # pragma: no cover
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` is missing on 0.4.x; a psum of ones is the
-    portable spelling (constant-folded by XLA)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+        kw["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kw)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a per-device list on jax
-    0.4.x and a flat dict on newer jax; normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
-def tpu_compiler_params(**kwargs):
-    """pltpu.CompilerParams (new) / TPUCompilerParams (0.4.x)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """``Compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())

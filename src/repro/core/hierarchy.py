@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import axis_size as _axis_size
 from repro.core.compat import shard_map as _shard_map
 
 
@@ -58,7 +57,7 @@ def hierarchical_allreduce(x: jax.Array, mesh: Mesh, *,
 
     def f(xs):
         # xs: local shard, shape (n, ...)
-        n_intra = _axis_size(intra_axis)
+        n_intra = jax.lax.axis_size(intra_axis)
         # phase 1: reduce-scatter along intra axis over the leading dim
         shard = jax.lax.psum_scatter(xs, intra_axis, scatter_dimension=0,
                                      tiled=True)

@@ -14,7 +14,8 @@ The manager provides:
   * a budget-enforcing paged KV pool (``KVBudget`` + ``PagedKV``) for the
     ``repro.serve`` engine: tier-1 page quotas and tier-2 byte budgets as
     first-class, contended resources;
-  * capability detection so the same code runs on CPU (tests) and TPU.
+  * memory-kind detection (``pinned_host`` where the backend has it) so
+    the same code runs on CPU (tests) and TPU.
 """
 
 from __future__ import annotations
@@ -28,12 +29,10 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 
 def tier2_memory_kind() -> Optional[str]:
-    """The platform's capacity-tier memory kind, or None if unsupported."""
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:  # pragma: no cover
-        return None
+    """The platform's capacity-tier memory kind, or None if it has none.
+    A backend that fails to list its memories raises: tier-2 is never
+    dropped in silence."""
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
     for kind in ("pinned_host", "unpinned_host", "host"):
         if kind in kinds:
             return kind
@@ -50,10 +49,7 @@ def to_tier2(sharding):
     kind = tier2_memory_kind()
     if kind is None:
         return sharding
-    try:
-        return sharding.with_memory_kind(kind)
-    except Exception:  # pragma: no cover
-        return sharding
+    return sharding.with_memory_kind(kind)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
@@ -133,7 +132,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B_mat: jax.Array,
             jax.ShapeDtypeStruct((Bsz, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xk, dtk, A.astype(jnp.float32), bk, ck, D.astype(jnp.float32),

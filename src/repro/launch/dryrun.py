@@ -20,11 +20,8 @@ import sys
 import traceback
 from pathlib import Path
 
-import jax
-
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-
 from repro.configs import ARCHS
+from repro.launch.cache import enable_compile_cache
 from repro.launch.dryrun_cell import lower_cell
 from repro.obs.console import emit
 from repro.models.config import SHAPES
@@ -93,4 +90,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

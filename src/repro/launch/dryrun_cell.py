@@ -188,13 +188,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, *,
         shape = ShapeConfig(shape.name, shape.kind, shape.seq_len,
                             max(1, shape.global_batch // micro), microbatches=1)
 
-    if dp_mode == "hierarchical":
-        from repro.sharding.profiles import hierarchical_unsafe
-        reason = hierarchical_unsafe(cfg)
-        if reason:
-            return {"arch": arch, "shape": shape_name,
-                    "mesh": "multi" if multi_pod else "single",
-                    "status": "SKIP", "reason": reason}
     rules = make_rules(cfg, shape, mesh, fsdp=fsdp, dp_mode=dp_mode)
     if rules_patch:
         rules = rules.override(**rules_patch)

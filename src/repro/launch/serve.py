@@ -39,8 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core.compat import mesh_context
 from repro.core.tiering import KVBudget
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models.api import build_model
 from repro.models.config import ShapeConfig
@@ -106,9 +106,11 @@ def _engine_mode(args, cfg, model) -> int:
         "arch": cfg.name, "mode": "engine",
         "lease": args.pool if args.pool != "none" else None,
         "requests": len(handles),
-        "latency": latency_summary(handles),
+        "short_requests": sum(len(h.tokens) < h.request.max_new_tokens
+                              for h in handles),
+        "modeled_latency": latency_summary(handles),
         "stats": stats,
-        "wall_s": round(wall, 2),
+        "host_wall_s": wall,
         "sample_tokens": handles[0].tokens[:8] if handles else [],
     }
     if tracer is not None:
@@ -192,12 +194,12 @@ def _disagg_mode(args, cfg, model) -> int:
         "prefill_pods": n_pre, "decode_pods": n_dec,
         "requests": len(handles),
         "handoffs": cluster.handoffs, "colocated": cluster.colocated,
-        "latency": latency_summary(handles),
-        "kv_transit_s": {
+        "modeled_latency": latency_summary(handles),
+        "modeled_kv_transit_s": {
             "mean": sum(transits) / max(1, len(transits)),
             "max": transits[-1] if transits else 0.0,
         },
-        "wall_s": round(wall, 2),
+        "host_wall_s": wall,
         "sample_tokens": handles[0].tokens[:8] if handles else [],
     }
     if tracer is not None:
@@ -259,14 +261,14 @@ def _multitenant_mode(args, cfg, model, ecfg, tracer=None) -> int:
     wall = time.time() - t0
     out = {"arch": cfg.name, "mode": "multitenant",
            "tenants": args.tenants, "tier1_pages": tier1,
-           "wall_s": round(wall, 2), "arbiter": arb.stats(), "per_tenant": {}}
+           "host_wall_s": wall, "arbiter": arb.stats(), "per_tenant": {}}
     failed = 0
     for n, handles in zip(names, results):
         st = engines[n].stats()
         failed += st["failed_oom"]
         out["per_tenant"][n] = {
             "requests": len(handles),
-            "latency": latency_summary(handles),
+            "modeled_latency": latency_summary(handles),
             "swaps": st["preempt_swaps"],
             "recomputes": st["preempt_recomputes"],
             "tput_busy_tok_s": st["throughput_busy_tok_s"],
@@ -292,7 +294,7 @@ def _legacy_batch_mode(args, cfg, model) -> int:
     decode_fn = jax.jit(serve_rt.make_decode_step(model),
                         donate_argnums=(1,))
 
-    with use_rules(rules, mesh), mesh_context(mesh):
+    with use_rules(rules, mesh), jax.set_mesh(mesh):
         cache = model.init_cache(args.batch, max_seq, dtype=jnp.float32)
         t0 = time.time()
         if cfg.family == "encdec":
@@ -324,8 +326,8 @@ def _legacy_batch_mode(args, cfg, model) -> int:
         "arch": cfg.name, "mode": "batch",
         "batch": args.batch, "prompt": args.prompt,
         "generated": toks.shape[1],
-        "prefill_s": round(t_prefill, 3),
-        "decode_tok_per_s": round(tokens_per_s, 1),
+        "host_prefill_s": t_prefill,
+        "host_decode_tok_per_s": tokens_per_s,
         "sample_tokens": toks[0, :8].tolist(),
     })
     return 0
@@ -407,4 +409,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

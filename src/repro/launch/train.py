@@ -22,10 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core.compat import IS_OLD_JAX, mesh_context
 from repro.core.tiering import TieringPolicy, offload_state_shardings
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models.api import build_model
 from repro.models.config import ShapeConfig
@@ -34,7 +34,7 @@ from repro.optim.adamw import AdamW
 from repro.runtime import train as train_rt
 from repro.runtime.ft import FaultTolerantLoop, StragglerMonitor
 from repro.sharding.partition import use_rules
-from repro.sharding.profiles import hierarchical_unsafe, make_rules
+from repro.sharding.profiles import make_rules
 
 
 def main(argv=None):
@@ -93,11 +93,6 @@ def main(argv=None):
         mesh = make_smoke_mesh()
     multi_pod = "pod" in mesh.axis_names
     dp_mode = args.dp_mode if multi_pod else "auto"
-    if dp_mode == "hierarchical":
-        reason = hierarchical_unsafe(cfg)
-        if reason:
-            warn(f"{reason}; falling back to dp_mode=auto")
-            dp_mode = "auto"
     rules = make_rules(cfg, shape, mesh, fsdp=False, dp_mode=dp_mode)
     tcfg = train_rt.TrainStepConfig(dp_mode=dp_mode,
                                     compress_pod=args.compress_pod,
@@ -107,6 +102,10 @@ def main(argv=None):
     state = train_rt.init_state(model, optimizer, rng, tcfg)
     step_fn, state_sh = train_rt.make_train_step(
         model, optimizer, shape, mesh=mesh, rules=rules, tcfg=tcfg)
+    if state_sh is not None:
+        # start on the mesh, where the step leaves the state: from device
+        # 0 alone, the second step would compile the step again
+        state = jax.device_put(state, state_sh)
     if state_sh is not None and tier_policy is not None \
             and tier_policy.offload_optimizer:
         state_sh = offload_state_shardings(state_sh, tier_policy)
@@ -114,14 +113,11 @@ def main(argv=None):
     pipe = DataPipeline(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                    global_batch=args.batch))
 
-    # jax 0.4.x XLA hard-crashes (IsManualSubgroup CHECK) when donation
-    # meets the partially-manual pod shard_map; trade memory for survival.
-    donate = () if (dp_mode == "hierarchical" and IS_OLD_JAX) else (0,)
-    jit_step = jax.jit(step_fn, donate_argnums=donate)
+    jit_step = jax.jit(step_fn, donate_argnums=(0,))
 
     def train_step(state, batch):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        with use_rules(rules, mesh), mesh_context(mesh):
+        with use_rules(rules, mesh), jax.set_mesh(mesh):
             return jit_step(state, batch)
 
     ckpt_dir = Path(args.ckpt_dir)
@@ -146,11 +142,14 @@ def main(argv=None):
     dt = time.time() - t0
 
     losses = [h["loss"] for h in loop.history]
+    step_s = [h["host_step_s"] for h in loop.history]
     emit_json({
         "arch": cfg.name, "steps": args.steps,
         "devices": len(jax.devices()), "mesh": dict(zip(mesh.axis_names,
                                                         mesh.devices.shape)),
         "dp_mode": dp_mode,
+        "state_devices": len({d for leaf in jax.tree.leaves(state.params)
+                              for d in leaf.devices()}),
         "lease": (None if lease is None else {
             "pods": list(lease.allocation.pod_ids),
             "accels": lease.n_accels,
@@ -158,7 +157,12 @@ def main(argv=None):
             "offload_optimizer": tier_policy.offload_optimizer}),
         "loss_first": losses[0], "loss_last": losses[-1],
         "loss_drop": losses[0] - losses[-1],
-        "wall_s": round(dt, 1), "s_per_step": round(dt / args.steps, 3),
+        # host wall clock; each step is timed after block_until_ready,
+        # and the first one includes compilation
+        "host_wall_s": dt,
+        "host_first_step_s": step_s[0],
+        "host_steady_step_s": (float(np.median(step_s[1:]))
+                               if len(step_s) > 1 else None),
         "straggler_events": len(loop.monitor.events),
         "restarts": loop.restarts,
     })
@@ -166,4 +170,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

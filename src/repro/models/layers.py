@@ -390,7 +390,13 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
     """Mean next-token cross entropy.  logits: (B,S,V), labels: (B,S)."""
     logits = logits.astype(jnp.float32)
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    # the gold logit as a masked sum over the (possibly model-sharded)
+    # vocab axis, not a gather: XLA's SPMD partitioner CHECK-fails on the
+    # batched gather when the pod axis is manual (hierarchical dp) and
+    # the vocab axis is sharded
+    vocab_ids = jnp.arange(logits.shape[-1], dtype=labels.dtype)
+    gold = jnp.sum(jnp.where(labels[..., None] == vocab_ids, logits, 0.0),
+                   axis=-1)
     nll = logz - gold
     if mask is not None:
         nll = nll * mask

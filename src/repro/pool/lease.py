@@ -200,9 +200,10 @@ class Lease:
         devices — on a real deployment each host binds its slice; the
         mesh *shape* logic is identical).
         """
+        from repro.launch.mesh import make_mesh
         devs = list(devices) if devices is not None else list(jax.devices())
         shape, axes = self.mesh_shape(len(devs))
-        mesh = jax.make_mesh(shape, axes, devices=devs)
+        mesh = make_mesh(shape, axes, devices=devs)
         return mesh, self.tiering_policy()
 
 class ResourcePool:

@@ -28,6 +28,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 
@@ -110,7 +111,8 @@ class FaultTolerantLoop:
                     self.failure_hook(step)  # may raise (injected failure)
                 batch = self.pipeline.peek_step(step)
                 t0 = time.time()
-                new_state, metrics = self.train_step(state, batch)
+                new_state, metrics = jax.block_until_ready(
+                    self.train_step(state, batch))
                 dt = time.time() - t0
                 return new_state, metrics, dt
 
@@ -125,7 +127,7 @@ class FaultTolerantLoop:
                 continue
 
             self.monitor.observe(step, dt)
-            self.history.append({"step": step, **{
+            self.history.append({"step": step, "host_step_s": dt, **{
                 k: float(np.asarray(v)) for k, v in metrics.items()}})
             step += 1
             self.pipeline.state.step = step

@@ -109,7 +109,6 @@ def make_lease_session(model: Model, shape: ShapeConfig,
     hard-coded per deployment.  The returned steps run scoped to the
     lease's mesh/rules so GSPMD honors the leased model parallelism.
     """
-    from repro.core.compat import mesh_context
     from repro.sharding.partition import use_rules
 
     mesh, policy = lease.materialize()
@@ -119,7 +118,7 @@ def make_lease_session(model: Model, shape: ShapeConfig,
         jitted = jax.jit(fn, donate_argnums=donate)
 
         def call(*args):
-            with use_rules(rules, mesh), mesh_context(mesh):
+            with use_rules(rules, mesh), jax.set_mesh(mesh):
                 return jitted(*args)
         return call
 

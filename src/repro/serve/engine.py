@@ -454,15 +454,30 @@ class Engine:
                    transport=transport, route=route, tracer=tracer)
 
     def _scoped(self, jitted):
+        # the closure captures the mesh and rules, never ``self``: stored
+        # on the engine, a closure over ``self`` would be a reference
+        # cycle that keeps its params and page pool on the device until
+        # a garbage collection happens to run
+        mesh, rules = self.mesh, self.rules
+
         def call(*args):
             with contextlib.ExitStack() as stack:
-                if self.mesh is not None:
-                    from repro.core.compat import mesh_context
+                if mesh is not None:
                     from repro.sharding.partition import use_rules
-                    stack.enter_context(use_rules(self.rules, self.mesh))
-                    stack.enter_context(mesh_context(self.mesh))
+                    stack.enter_context(use_rules(rules, mesh))
+                    stack.enter_context(jax.set_mesh(mesh))
                 return jitted(*args)
         return call
+
+    def lower_decode(self):
+        """The paged-decode step as the device runs it for a full slot
+        array (``jax.stages.Lowered``): ``.compile().as_text()`` shows
+        whether the Pallas kernel is there (``tpu_custom_call``) or was
+        interpreted."""
+        rows = self.cfg.max_slots
+        return self._scoped(self._decode_jit.lower)(
+            self.params, jnp.zeros((rows, 1), jnp.int32), self._pool,
+            jnp.asarray(self._table), jnp.asarray(self._lengths))
 
     # ---- client API ------------------------------------------------------
     def submit(self, request: Request) -> RequestHandle:

@@ -25,7 +25,6 @@ from typing import Dict, Optional, Tuple
 
 from jax.sharding import Mesh
 
-from repro.core.compat import IS_OLD_JAX
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.sharding.partition import Rules
 
@@ -37,30 +36,11 @@ def _prod(xs):
     return out
 
 
-def hierarchical_unsafe(cfg: ModelConfig) -> Optional[str]:
-    """Detect archs that hard-crash jax 0.4.x XLA under hierarchical dp.
-
-    The partially-manual ('pod') shard_map trips a partitioner CHECK
-    (``IsManualSubgroup``, hlo_sharding_util.cc) on the backward of the
-    per-layer norm-scale broadcast inside the layer scan — reproduced
-    minimally as scan + parametric-norm multiply + grad under a manual
-    subgroup; no rule table avoids it.  Every parametric-norm arch is
-    affected (the tied-embedding qwen family is the motivating case from
-    the ROADMAP); OLMo's non-parametric LN is safe, as is new-XLA jax.
-    Returns the reason string, or None when hierarchical dp is safe.
-    """
-    if not IS_OLD_JAX:
-        return None
-    if cfg.norm_type != "nonparam_ln":
-        tied = " tied-embedding" if cfg.tie_embeddings else ""
-        return (f"{cfg.name}:{tied} arch with parametric {cfg.norm_type} "
-                f"scales in the layer scan trips the jax 0.4.x XLA "
-                f"IsManualSubgroup CHECK under hierarchical dp")
-    return None
-
-
 def make_rules(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
                fsdp: bool = True, dp_mode: str = "auto") -> Rules:
+    """The rule table is the same for both ``dp_mode`` values: the
+    hierarchical train step strips the manual ``pod`` axis from it
+    inside its shard_map (``Rules.strip_axis``)."""
     ax = dict(zip(mesh.axis_names, mesh.devices.shape))
     model_n = ax.get("model", 1)
     data_axes = tuple(a for a in ("pod", "data") if a in ax)
@@ -112,17 +92,6 @@ def make_rules(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
         "ssm_inner": "model",
         "ssm_inner_norm": None,
     }
-
-    # jax 0.4.x XLA landmine: refuse rule sets that would hard-crash the
-    # process (SIGABRT, not an exception) at compile time.  Callers catch
-    # the ValueError and fall back to flat dp (launch/train.py does this
-    # automatically).
-    if dp_mode == "hierarchical" and "pod" in ax:
-        reason = hierarchical_unsafe(cfg)
-        if reason:
-            raise ValueError(
-                f"refusing hierarchical sharding rules: {reason}; use "
-                f"dp_mode='auto' (flat GSPMD) for this arch on jax 0.4.x")
 
     if shape.kind == "decode":
         # one-token queries: context parallelism is meaningless; spread the

@@ -30,7 +30,8 @@ def test_hierarchical_allreduce_equals_flat():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import hierarchy as h
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         x = jnp.arange(32.0).reshape(8, 4)
         flat = h.flat_allreduce(x, mesh, ("pod", "data"))
         hier = h.hierarchical_allreduce(x, mesh, intra_axis="data",
@@ -53,7 +54,8 @@ def test_hierarchical_reduces_cross_pod_bytes():
         import jax, jax.numpy as jnp, re
         from repro.core import hierarchy as h
         from repro.launch import hlo_analysis as H
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("pod", "data"))
         x = jnp.zeros((1024, 64))
 
         def coll_report(fn):
@@ -82,7 +84,8 @@ def test_int8_compression_error_feedback():
         err = np.abs(np.asarray(h.dequantize_int8(q, s) - x))
         assert err.max() <= float(s) * 0.51 + 1e-9
         # error feedback: mean of compressed reductions converges to true mean
-        mesh = jax.make_mesh((2,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,), ("pod",))
         from repro.core.compat import shard_map
         from jax.sharding import PartitionSpec as P
         def step(x, r):
@@ -112,13 +115,13 @@ def test_train_step_hierarchical_matches_auto():
         from repro.configs import SMOKE_ARCHS
         from repro.models.api import build_model, input_specs
         from repro.models.config import ShapeConfig
-        from repro.core.compat import mesh_context
+        from repro.launch.mesh import make_mesh
         from repro.optim.adamw import AdamW
         from repro.runtime import train as tr
         from repro.sharding.partition import use_rules
         from repro.sharding.profiles import make_rules
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = SMOKE_ARCHS["olmo-1b"]
         shape = ShapeConfig("train_4k", "train", 32, 8)
         rules = make_rules(cfg, shape, mesh, fsdp=False)
@@ -134,7 +137,7 @@ def test_train_step_hierarchical_matches_auto():
             state = tr.init_state(model, opt, rng, tcfg)
             step, _ = tr.make_train_step(model, opt, shape, mesh=mesh,
                                          rules=rules, tcfg=tcfg)
-            with use_rules(rules, mesh), mesh_context(mesh):
+            with use_rules(rules, mesh), jax.set_mesh(mesh):
                 new_state, metrics = jax.jit(step)(state, batch)
             results[mode] = (float(metrics["loss"]),
                              np.asarray(jax.tree.leaves(new_state.params)[0],
@@ -150,43 +153,71 @@ def test_train_step_hierarchical_matches_auto():
     assert "OK" in out
 
 
-def test_tied_parametric_norm_arch_refused_not_crashed():
-    """jax 0.4.x landmine (ROADMAP): hierarchical dp with the tied-
-    embedding qwen family used to SIGABRT the whole process inside XLA
-    (IsManualSubgroup CHECK).  make_rules must now detect the combination
-    and raise a catchable error instead, and the launcher falls back to
-    flat dp; on new-XLA jax the hierarchical path stays available."""
+def test_train_cli_on_two_pod_lease():
+    """launch/train.py on a two-pod lease (mesh pod=2, data=2, model=1),
+    as chip_smoke.py --four-chips runs it: both dp modes keep the state on
+    all 4 devices, agree on the loss, and compile the step once (a state
+    that starts off the mesh makes the second step compile again)."""
     out = run_with_devices("""
-        import jax, pytest
+        import contextlib, io, json, logging
+        import jax
+        from repro.launch import train
+
+        compiles = []
+
+        class CountStepCompiles(logging.Handler):
+            def emit(self, record):
+                if "compilation of jit(step)" in record.getMessage():
+                    compiles.append(record)
+
+        logging.getLogger("jax").addHandler(CountStepCompiles())
+        jax.config.update("jax_log_compiles", True)
+        res = {}
+        for mode in ("auto", "hierarchical"):
+            compiles.clear()
+            buf = io.StringIO()
+            with contextlib.redirect_stdout(buf):
+                rc = train.main([
+                    "--arch", "qwen1.5-0.5b", "--smoke", "--steps", "3",
+                    "--batch", "8", "--seq", "32", "--pool", "scalepool",
+                    "--pool-accels", "16", "--dp-mode", mode,
+                    "--ckpt-every", "1000000", "--ckpt-dir", "/dev/null"])
+            res[mode] = dict(json.loads(buf.getvalue()), rc=rc,
+                             step_compiles=len(compiles))
+        print(json.dumps(res))
+    """, n_devices=4)
+    res = json.loads(out.strip().splitlines()[-1])
+    for mode, r in res.items():
+        assert r["dp_mode"] == mode
+        assert r["mesh"] == {"pod": 2, "data": 2, "model": 1}
+        assert r["state_devices"] == 4
+        assert r["step_compiles"] == 1, r
+        assert r["rc"] == 0 and r["loss_last"] < r["loss_first"]
+    # chip_smoke.py's tolerance: same math, another reduction order
+    a, h = res["auto"], res["hierarchical"]
+    for key in ("loss_first", "loss_last"):
+        assert abs(a[key] - h[key]) <= 2e-3 * a[key], (key, a[key], h[key])
+
+
+def test_tied_parametric_norm_arch_refused_not_crashed():
+    """Hierarchical dp is offered to every arch: the tied-embedding,
+    parametric-norm qwen family gets hierarchical sharding rules just as
+    olmo (non-parametric LN) does, and both match their auto tables."""
+    out = run_with_devices("""
         from repro.configs import SMOKE_ARCHS
-        from repro.core.compat import IS_OLD_JAX
+        from repro.launch.mesh import make_mesh
         from repro.models.config import ShapeConfig
-        from repro.sharding.profiles import hierarchical_unsafe, make_rules
+        from repro.sharding.profiles import make_rules
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = ShapeConfig("t", "train", 32, 8)
-        qwen = SMOKE_ARCHS["qwen1.5-0.5b"]   # tied + rmsnorm: the landmine
-        olmo = SMOKE_ARCHS["olmo-1b"]        # tied + nonparam LN: safe
-
-        assert hierarchical_unsafe(olmo) is None
-        # safe combos always construct
-        make_rules(qwen, shape, mesh, fsdp=False)
-        make_rules(qwen, shape, mesh, fsdp=False, dp_mode="auto")
-        make_rules(olmo, shape, mesh, fsdp=False, dp_mode="hierarchical")
-
-        if IS_OLD_JAX:
-            assert hierarchical_unsafe(qwen) is not None
-            try:
-                make_rules(qwen, shape, mesh, fsdp=False,
-                           dp_mode="hierarchical")
-            except ValueError as e:
-                assert "IsManualSubgroup" in str(e)
-            else:
-                raise AssertionError("unsafe combo was not refused")
-        else:
-            assert hierarchical_unsafe(qwen) is None
-            make_rules(qwen, shape, mesh, fsdp=False,
-                       dp_mode="hierarchical")
+        for name in ("qwen1.5-0.5b", "olmo-1b"):
+            cfg = SMOKE_ARCHS[name]
+            hier = make_rules(cfg, shape, mesh, fsdp=False,
+                              dp_mode="hierarchical")
+            auto = make_rules(cfg, shape, mesh, fsdp=False, dp_mode="auto")
+            assert hier.table == auto.table, name
+            assert hier.table["batch"] == ("pod", "data"), name
         print("OK")
     """)
     assert "OK" in out

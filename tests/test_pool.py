@@ -477,7 +477,6 @@ def test_lease_drives_real_train_step(rng):
     from repro.runtime import train as train_rt
     from repro.sharding.partition import use_rules
     from repro.sharding.profiles import make_rules
-    from repro.core.compat import mesh_context
     from repro.core.tiering import offload_state_shardings
     from conftest import make_batch
 
@@ -496,7 +495,7 @@ def test_lease_drives_real_train_step(rng):
                                               rules=rules)
     state_sh = offload_state_shardings(state_sh, policy)
     batch = make_batch(rng, cfg, B=2, S=16)
-    with use_rules(rules, mesh), mesh_context(mesh):
+    with use_rules(rules, mesh), jax.set_mesh(mesh):
         new_state, metrics = jax.jit(step)(state, batch)
     assert jnp.isfinite(metrics["loss"])
     assert metrics["loss"].shape == ()

@@ -249,6 +249,34 @@ def test_engine_lease_and_local_identical(model):
     assert [h.tokens for h in local] == [h.tokens for h in leased]
 
 
+@pytest.mark.parametrize("build", ["local", "lease"])
+def test_dropped_engine_freed_without_gc(model, params, build):
+    """An engine that served requests is freed by reference counting
+    alone: no cycle keeps its params and page pool alive until the next
+    garbage collection (on a chip that is HBM the next engine needs)."""
+    import gc
+    import weakref
+    if build == "lease":
+        from repro.pool import smoke_pool
+        lease = smoke_pool("scalepool").lease("serve-free", 4, tier2_gb=64,
+                                              kv_gb=1.0)
+    gc.collect()
+    gc.disable()
+    try:
+        eng = (Engine.local(model, _cfg(), params=params) if build == "local"
+               else Engine.from_lease(model, lease, _cfg(), params=params))
+        handles = run_trace(eng, _trace(n=4))
+        eng.lower_decode()
+        assert all(h.status is RequestStatus.DONE for h in handles)
+        pool_leaf = weakref.ref(jax.tree.leaves(eng._pool)[0])
+        ref = weakref.ref(eng)
+        del eng, handles
+        assert ref() is None, gc.get_referrers(ref())
+        assert pool_leaf() is None
+    finally:
+        gc.enable()
+
+
 # ---------------------------------------------------------------------------
 # engine semantics: recycling, recompute preemption, OOM, stats
 # ---------------------------------------------------------------------------
