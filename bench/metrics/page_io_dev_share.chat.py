@@ -1,0 +1,6 @@
+"""page_io_dev_share.chat"""
+from bench import readers
+
+
+def read(rec):
+    return readers.program_share(rec, "other")
