@@ -6,9 +6,9 @@ The lens the repro's attribution claims are argued through:
               modeled clock, recorded into a bounded ring buffer
               ("flight recorder", O(1) append); ``NULL_TRACER`` is the
               zero-cost disabled default every subsystem falls back to
-    metrics — ``MetricsRegistry``: hierarchical counter/gauge/histogram
-              registry; the legacy per-subsystem ``stats()`` dicts are
-              thin adapters over it
+    metrics — ``MetricsRegistry``: hierarchical counter/gauge registry;
+              the legacy per-subsystem ``stats()`` dicts are thin
+              adapters over it
     export  — Chrome/Perfetto ``trace_event`` JSON export (tracks =
               tenants, engines, links, pool) and the per-link
               utilization / queueing-delay report that decomposes a
@@ -33,8 +33,7 @@ from repro.obs.export import (format_link_report, link_report,
                               link_report_from_trace, link_tier,
                               tier_report, to_chrome_trace,
                               validate_trace_events, write_chrome_trace)
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               adapt, write_json)
+from repro.obs.metrics import Counter, Gauge, MetricsRegistry, write_json
 from repro.obs.trace import (CAT_ARBITER, CAT_ENGINE, CAT_FABRIC, CAT_KV,
                              CAT_LINK, CAT_REQUEST, CAT_SCHED, NULL_TRACER,
                              Event, JsonlSink, NullTracer, Tracer,
@@ -43,9 +42,9 @@ from repro.obs.trace import (CAT_ARBITER, CAT_ENGINE, CAT_FABRIC, CAT_KV,
 
 __all__ = [
     "CAT_ARBITER", "CAT_ENGINE", "CAT_FABRIC", "CAT_KV", "CAT_LINK",
-    "CAT_REQUEST", "CAT_SCHED", "Counter", "Event", "Gauge", "Histogram",
-    "JsonlSink", "MetricsRegistry", "NULL_TRACER", "NullTracer", "Tracer",
-    "adapt", "events_from_jsonl", "format_link_report", "link_report",
+    "CAT_REQUEST", "CAT_SCHED", "Counter", "Event", "Gauge", "JsonlSink",
+    "MetricsRegistry", "NULL_TRACER", "NullTracer", "Tracer",
+    "events_from_jsonl", "format_link_report", "link_report",
     "link_report_from_trace", "link_tier", "resolve",
     "rotated_jsonl_paths", "tier_report",
     "to_chrome_trace", "validate_trace_events", "write_chrome_trace",

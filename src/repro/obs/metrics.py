@@ -15,16 +15,13 @@ working as *thin adapters* over the registry snapshot, so nothing
 downstream breaks while all new reporting (benchmark ``--json``,
 ``scripts/trace_report.py``, CI artifacts) reads the one schema.
 
-Three metric kinds, deliberately minimal:
+Two metric kinds, deliberately minimal:
 
 ``Counter``
     Monotone count (events, bytes).  ``inc`` only.
 ``Gauge``
     Point-in-time value of any JSON-serializable type (numbers for
     dashboards, the odd string label for identity fields).
-``Histogram``
-    Bounded reservoir of observations with deterministic nearest-rank
-    percentiles — same indexing as ``serve.trace.latency_summary``.
 
 Values are stored exactly as given (no float coercion): the adapters
 must reproduce the legacy dicts bit-identically.
@@ -33,7 +30,6 @@ must reproduce the legacy dicts bit-identically.
 from __future__ import annotations
 
 import json
-import math
 from typing import Any, Dict, List, Optional
 
 
@@ -63,39 +59,6 @@ class Gauge:
         return self.value
 
 
-class Histogram:
-    """Reservoir histogram: keeps up to ``cap`` observations (drops the
-    tail deterministically, counting drops) and summarizes with
-    nearest-rank percentiles (``ceil(p*n) - 1`` into the sorted
-    sample — the repo-wide convention)."""
-
-    __slots__ = ("cap", "values", "count", "total")
-
-    def __init__(self, cap: int = 4096):
-        self.cap = cap
-        self.values: List[float] = []
-        self.count = 0
-        self.total = 0.0
-
-    def observe(self, v: float) -> None:
-        self.count += 1
-        self.total += v
-        if len(self.values) < self.cap:
-            self.values.append(v)
-
-    def get(self) -> Dict[str, float]:
-        return self.summary()
-
-    def summary(self) -> Dict[str, float]:
-        if not self.values:
-            return {"n": 0, "mean": 0.0, "p50": 0.0, "p95": 0.0,
-                    "max": 0.0}
-        vs = sorted(self.values)
-        pct = lambda p: vs[max(0, math.ceil(p * len(vs)) - 1)]
-        return {"n": self.count, "mean": self.total / self.count,
-                "p50": pct(0.50), "p95": pct(0.95), "max": vs[-1]}
-
-
 class MetricsRegistry:
     """Get-or-create store of named metrics.  Names are ``/``-separated
     paths; ``snapshot()`` flattens to ``{path: value}`` and ``tree()``
@@ -119,9 +82,6 @@ class MetricsRegistry:
 
     def gauge(self, name: str) -> Gauge:
         return self._get(name, Gauge)
-
-    def histogram(self, name: str) -> Histogram:
-        return self._get(name, Histogram)
 
     def set(self, name: str, value) -> None:
         """Shorthand: ``gauge(name).set(value)`` — the bulk of the
@@ -156,13 +116,6 @@ class MetricsRegistry:
                                      f"leaf metric {p!r}")
             node[parts[-1]] = m.get()
         return out
-
-
-def adapt(snapshot: Dict[str, Any], mapping: Dict[str, str]) -> Dict[str, Any]:
-    """Thin legacy-``stats()`` adapter: ``{old_key: registry_path}`` →
-    ``{old_key: value}``.  Raises on a missing path so schema drift is
-    an error, not a silently absent key."""
-    return {old: snapshot[path] for old, path in mapping.items()}
 
 
 def write_json(path: str, name: str, metrics: Dict[str, Any], *,

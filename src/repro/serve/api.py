@@ -72,6 +72,9 @@ class RequestHandle:
                                        # pages spent in flight on the fabric
                                        # (disaggregated prefill->decode
                                        # handoff; 0.0 when colocated)
+    admit_step: Optional[int] = None   # the engine's ``steps`` during the
+                                       # step whose prefill first ran it
+                                       # (a recompute does not reset it)
 
     @property
     def done(self) -> bool:

@@ -59,6 +59,12 @@ pool arrays live on the arbiter, and ``allowance()`` (the live max-min
 share) replaces the fixed quota in the pressure/resume decisions.  A
 lone tenant's allowance is the whole pool, so single-tenant behavior is
 bit-identical to the private path.
+
+Host phases are also marked on the profiler's own clock
+(``jax.profiler.TraceAnnotation``, names in ``SPAN_NAMES``), the clock
+the device planes of a profile share: a device idle gap can be put down
+to the innermost engine span over it.  Outside a profile a span costs
+about a microsecond and records nothing.
 """
 
 from __future__ import annotations
@@ -79,6 +85,18 @@ from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import CAT_ENGINE, CAT_KV, CAT_REQUEST, resolve
 from repro.serve.api import (EngineConfig, Request, RequestHandle,
                              RequestStatus, ServeCostModel)
+
+# Host spans on the profiler's clock, one per engine phase.  The modeled
+# tracer's ``prefill``/``decode``/``spill``/``fetch`` spans sit at the same
+# sites.  ``step`` nests ``admit`` (``prefill`` with its ``read``, then
+# ``page_write``) and ``decode`` (its ``read`` and ``emit``); ``fetch`` and
+# ``spill`` are the tier-2 page moves.
+SPAN_NAMES = ("engine.step", "engine.admit", "engine.prefill",
+              "engine.page_write", "engine.decode", "engine.read",
+              "engine.emit", "engine.fetch", "engine.spill")
+(SPAN_STEP, SPAN_ADMIT, SPAN_PREFILL, SPAN_PAGE_WRITE, SPAN_DECODE,
+ SPAN_READ, SPAN_EMIT, SPAN_FETCH, SPAN_SPILL) = SPAN_NAMES
+_span = jax.profiler.TraceAnnotation
 
 
 def _dtype(d):
@@ -109,11 +127,14 @@ def evict_pages(pool, kv, st, logicals, engine, t) -> float:
     tenant's revocation charge).  Shared by ``Engine._evict_or_drop``
     and ``PoolArbiter.reclaim`` so the two eviction paths cannot
     diverge."""
-    table = kv.page_table(st.rid)
-    idx = jnp.asarray(np.asarray([table[lp] for lp in logicals], np.int32))
-    gathered = jax.tree.map(lambda l: np.asarray(l[:, idx]), pool)
-    for i, lp in enumerate(logicals):
-        kv.evict(st.rid, lp, jax.tree.map(lambda g, i=i: g[:, i], gathered))
+    with _span(SPAN_SPILL, rid=st.rid, pages=len(logicals)):
+        table = kv.page_table(st.rid)
+        idx = jnp.asarray(np.asarray([table[lp] for lp in logicals],
+                                     np.int32))
+        gathered = jax.tree.map(lambda l: np.asarray(l[:, idx]), pool)
+        for i, lp in enumerate(logicals):
+            kv.evict(st.rid, lp,
+                     jax.tree.map(lambda g, i=i: g[:, i], gathered))
     st.handle.swaps += 1        # one spill episode: len(logicals) pages,
                                 # one bulk transfer over the capacity fabric
     cost = engine.charge_tier2(len(logicals) * kv.page_bytes, t)
@@ -307,6 +328,7 @@ class Engine:
                                    # throughput denominator that idle
                                    # inter-arrival gaps cannot dilute
         self._decoded_tokens = 0
+        self._pages_written = 0    # pages landed by _write_page
 
         # prefill buckets: page-aligned powers of two capped at the slot
         # capacity — the jit program count is bounded by len(buckets),
@@ -336,6 +358,13 @@ class Engine:
 
         self._decode_jit = jax.jit(paged_decode)
         self._decode_fn = self._scoped(self._decode_jit)
+
+    @property
+    def pages_written(self) -> int:
+        """Pages landed in the pool by prefill and handoff writes (one
+        ``_write_page`` each); tier-2 spills and fetches are counted in
+        ``kv.residency()``."""
+        return self._pages_written
 
     @property
     def _track(self) -> str:
@@ -616,65 +645,67 @@ class Engine:
         decode every running row one token.  Returns modeled seconds.
         Sub-phases receive the seconds already elapsed *within* this
         step so every event clock lands on the event's modeled time."""
-        dt = 0.0
-        if self.arbiter is not None:
-            # swap seconds another tenant's revocation charged to us
-            # since our last step: OUR pages rode the fabric, so OUR
-            # subsequent event clocks absorb the time
-            dt += self.arbiter.take_charge(self.tenant)
-        dt += self._relieve_pressure(dt)
-        dt += self._swap_in(dt)
-        dt += self._admit_handoffs(dt)
-        dt += self._admit(dt)
-        dt += self._decode_once(dt)
-        if (dt == 0.0 and self._queue and not self._paused  # repro: allow(no-float-equality) 0.0 is an exact no-work sentinel (no phase ran), never an accumulated time
-                and all(s is None for s in self._slots)):
-            # nothing runnable and the FIFO head has not arrived yet:
-            # idle-advance to its arrival (the same jump run_trace makes)
-            # so directly-submitted future-dated requests make progress
-            nxt = self._queue[0].request.arrival_time
-            if nxt > self.clock:
-                self.advance_clock(nxt)
-        if dt == 0.0:  # repro: allow(no-float-equality) same exact no-work sentinel as above
-            # every runnable row (or the pending handoff) is still
-            # waiting on KV in flight over the fabric: idle-advance to
-            # the earliest modeled page arrival so progress is made
-            gates = [s.ready_at for s in self._slots
-                     if s is not None and s.ready_at > self.clock]
-            if self._handoffs:
-                gates.append(self._handoffs[0].admit_at)
-            if gates:
-                nxt = min(gates)
+        with jax.profiler.StepTraceAnnotation(SPAN_STEP,
+                                              step_num=self.steps):
+            dt = 0.0
+            if self.arbiter is not None:
+                # swap seconds another tenant's revocation charged to us
+                # since our last step: OUR pages rode the fabric, so OUR
+                # subsequent event clocks absorb the time
+                dt += self.arbiter.take_charge(self.tenant)
+            dt += self._relieve_pressure(dt)
+            dt += self._swap_in(dt)
+            dt += self._admit_handoffs(dt)
+            dt += self._admit(dt)
+            dt += self._decode_once(dt)
+            if (dt == 0.0 and self._queue and not self._paused  # repro: allow(no-float-equality) 0.0 is an exact no-work sentinel (no phase ran), never an accumulated time
+                    and all(s is None for s in self._slots)):
+                # nothing runnable and the FIFO head has not arrived yet:
+                # idle-advance to its arrival (the same jump run_trace makes)
+                # so directly-submitted future-dated requests make progress
+                nxt = self._queue[0].request.arrival_time
                 if nxt > self.clock:
                     self.advance_clock(nxt)
-        self.clock += dt
-        if dt > 0.0:
-            self.busy_s += dt
-        self.steps += 1
-        if self.tracer.enabled:
-            # counter lanes (Perfetto renders these as area charts):
-            # physical free stack, pause-queue depth, live allowance.
-            # Values are identical between a private pool and a lone
-            # tenant under the arbiter (the fig9 transparency contract),
-            # so traced event streams stay bit-identical across both.
-            if self.steps == 1:
-                # pool geometry, once: the conservation baseline the
-                # repro.analysis sanitizer checks page counters against
-                self.tracer.instant(self._track, "kv_pool", self.clock,
-                                    cat=CAT_KV, pages=self.kv.num_pages)
-            self.tracer.counter(self._track, "free_pages", self.clock,
-                                float(self.kv.free_count), cat=CAT_KV)
-            self.tracer.counter(self._track, "paused", self.clock,
-                                float(len(self._paused)))
-            self.tracer.counter(self._track, "allowance", self.clock,
-                                float(self.kv.allowance()), cat=CAT_KV)
-            # hot_pages LAST in the step-end block: the sanitizer treats
-            # it as the tenant's authoritative residency sample and
-            # checks free + sum(hot) == pool against the same block's
-            # free_pages value
-            self.tracer.counter(self._track, "hot_pages", self.clock,
-                                float(self.kv.hot_used()), cat=CAT_KV)
-        return dt
+            if dt == 0.0:  # repro: allow(no-float-equality) same exact no-work sentinel as above
+                # every runnable row (or the pending handoff) is still
+                # waiting on KV in flight over the fabric: idle-advance to
+                # the earliest modeled page arrival so progress is made
+                gates = [s.ready_at for s in self._slots
+                         if s is not None and s.ready_at > self.clock]
+                if self._handoffs:
+                    gates.append(self._handoffs[0].admit_at)
+                if gates:
+                    nxt = min(gates)
+                    if nxt > self.clock:
+                        self.advance_clock(nxt)
+            self.clock += dt
+            if dt > 0.0:
+                self.busy_s += dt
+            self.steps += 1
+            if self.tracer.enabled:
+                # counter lanes (Perfetto renders these as area charts):
+                # physical free stack, pause-queue depth, live allowance.
+                # Values are identical between a private pool and a lone
+                # tenant under the arbiter (the fig9 transparency contract),
+                # so traced event streams stay bit-identical across both.
+                if self.steps == 1:
+                    # pool geometry, once: the conservation baseline the
+                    # repro.analysis sanitizer checks page counters against
+                    self.tracer.instant(self._track, "kv_pool", self.clock,
+                                        cat=CAT_KV, pages=self.kv.num_pages)
+                self.tracer.counter(self._track, "free_pages", self.clock,
+                                    float(self.kv.free_count), cat=CAT_KV)
+                self.tracer.counter(self._track, "paused", self.clock,
+                                    float(len(self._paused)))
+                self.tracer.counter(self._track, "allowance", self.clock,
+                                    float(self.kv.allowance()), cat=CAT_KV)
+                # hot_pages LAST in the step-end block: the sanitizer treats
+                # it as the tenant's authoritative residency sample and
+                # checks free + sum(hot) == pool against the same block's
+                # free_pages value
+                self.tracer.counter(self._track, "hot_pages", self.clock,
+                                    float(self.kv.hot_used()), cat=CAT_KV)
+            return dt
 
     # ---- internals -------------------------------------------------------
     def _running(self) -> List[_SlotState]:
@@ -898,17 +929,19 @@ class Engine:
         # clock) per cold page instead of one bulk transfer
         self.kv.prepare(len(cold) + max(0, want - self.kv.pages_of(st.rid)))
         if cold:
-            fetched = [self.kv.fetch(st.rid, lp) for lp in cold]
-            idx = jnp.asarray(np.asarray([p for p, _ in fetched], np.int32))
+            with _span(SPAN_FETCH, rid=st.rid, pages=len(cold)):
+                fetched = [self.kv.fetch(st.rid, lp) for lp in cold]
+                idx = jnp.asarray(np.asarray([p for p, _ in fetched],
+                                             np.int32))
 
-            def put(pool_leaf, *pages):     # one batched scatter, not one
-                stacked = jnp.stack(         # whole-pool copy per page
-                    [jnp.asarray(pg, pool_leaf.dtype) for pg in pages],
-                    axis=1)
-                return pool_leaf.at[:, idx].set(stacked)
+                def put(pool_leaf, *pages):     # one batched scatter, not one
+                    stacked = jnp.stack(         # whole-pool copy per page
+                        [jnp.asarray(pg, pool_leaf.dtype) for pg in pages],
+                        axis=1)
+                    return pool_leaf.at[:, idx].set(stacked)
 
-            self._pool = jax.tree.map(put, self._pool,
-                                      *[pl for _, pl in fetched])
+                self._pool = jax.tree.map(put, self._pool,
+                                          *[pl for _, pl in fetched])
             dt = self.charge_tier2(len(cold) * self.kv.page_bytes,
                                    self.clock + elapsed)
             if self.tracer.enabled:
@@ -947,8 +980,9 @@ class Engine:
             if slot is None or need > self.kv.hot_free:
                 break
             phys = self.kv.alloc(st.rid, need)
-            for i, payload in enumerate(ho.pages):
-                self._write_page(int(phys[i]), payload)
+            with _span(SPAN_PAGE_WRITE, rid=st.rid, pages=len(ho.pages)):
+                for i, payload in enumerate(ho.pages):
+                    self._write_page(int(phys[i]), payload)
             for lp, p in enumerate(phys):
                 self._table[slot, lp] = p
             self._place(st, slot)
@@ -964,36 +998,41 @@ class Engine:
         must not eat the free rows/pages the oldest paused sequence is
         waiting for (it would starve behind a steady arrival stream) —
         and it never evicts a paused sequence's residency either."""
-        dt = 0.0
-        while self._queue:
-            if self._paused:
-                break
-            st = self._queue[0]
-            if st.request.arrival_time > self.clock + elapsed + dt:
-                break   # not arrived yet on the modeled clock: admitting
-                        # (and decoding) it now would emit tokens BEFORE
-                        # its arrival and drive ttft/latency negative
-            if self.budget.pages_for(st.target_len) > self.kv.num_pages:
+        if not self._queue:
+            return 0.0
+        with _span(SPAN_ADMIT) as span:
+            dt, admitted = 0.0, 0
+            while self._queue:
+                if self._paused:
+                    break
+                st = self._queue[0]
+                if st.request.arrival_time > self.clock + elapsed + dt:
+                    break   # not arrived yet on the modeled clock: admitting
+                            # (and decoding) it now would emit tokens BEFORE
+                            # its arrival and drive ttft/latency negative
+                if self.budget.pages_for(st.target_len) > self.kv.num_pages:
+                    self._queue.popleft()
+                    st.handle.status = RequestStatus.FAILED_OOM
+                    st.handle.done_clock = self.clock + elapsed + dt
+                    if self.tracer.enabled:
+                        self.tracer.instant(self._track, "failed_oom",
+                                            st.handle.done_clock,
+                                            cat=CAT_REQUEST, rid=st.rid)
+                    continue
+                slot = self._free_slot()
+                eff = st.effective_prompt()
+                need = (self.budget.pages_for(st.target_len)
+                        if self.cfg.reserve_lifetime
+                        else self.budget.pages_for(len(eff) + 1))
+                if slot is None or need > self.kv.hot_free:
+                    break
+                # prefill BEFORE popping: while its pages are allocated the
+                # request must stay visible (as queue head) to the arbiter's
+                # demand accounting, or its fair share evaporates mid-admit
+                dt += self._prefill_into(st, slot, eff, elapsed + dt)
                 self._queue.popleft()
-                st.handle.status = RequestStatus.FAILED_OOM
-                st.handle.done_clock = self.clock + elapsed + dt
-                if self.tracer.enabled:
-                    self.tracer.instant(self._track, "failed_oom",
-                                        st.handle.done_clock,
-                                        cat=CAT_REQUEST, rid=st.rid)
-                continue
-            slot = self._free_slot()
-            eff = st.effective_prompt()
-            need = (self.budget.pages_for(st.target_len)
-                    if self.cfg.reserve_lifetime
-                    else self.budget.pages_for(len(eff) + 1))
-            if slot is None or need > self.kv.hot_free:
-                break
-            # prefill BEFORE popping: while its pages are allocated the
-            # request must stay visible (as queue head) to the arbiter's
-            # demand accounting, or its fair share evaporates mid-admit
-            dt += self._prefill_into(st, slot, eff, elapsed + dt)
-            self._queue.popleft()
+                admitted += 1
+            span.set_metadata(admitted=admitted)
         return dt
 
     def _prefill_into(self, st: _SlotState, slot: int,
@@ -1001,20 +1040,24 @@ class Engine:
         plen = len(eff)
         bucket = self._bucket_len(plen)
         self._buckets_used.add(bucket)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :plen] = eff
-        slot_cache = self.model.init_cache(1, bucket,
-                                           dtype=self._cache_dtype)
-        logits, cache = self._prefill_fn(self.params,
-                                         {"tokens": jnp.asarray(tokens)},
-                                         slot_cache, jnp.int32(plen - 1))
-        # the padded tail is real (wasted) compute on hardware: charge it
-        cost = self.cost.prefill_s(bucket)
-        if self.tracer.enabled:
-            self.tracer.span(self._track, "prefill",
-                             self.clock + elapsed, cost, cat=CAT_ENGINE,
-                             rid=st.rid, bucket=bucket, prompt_len=plen)
-        tok = int(np.argmax(np.asarray(logits)[0, -1]))
+        if st.handle.admit_step is None:
+            st.handle.admit_step = self.steps
+        with _span(SPAN_PREFILL, rid=st.rid, bucket=bucket, prompt_len=plen):
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :plen] = eff
+            slot_cache = self.model.init_cache(1, bucket,
+                                               dtype=self._cache_dtype)
+            logits, cache = self._prefill_fn(self.params,
+                                             {"tokens": jnp.asarray(tokens)},
+                                             slot_cache, jnp.int32(plen - 1))
+            # the padded tail is real (wasted) compute on hardware: charge it
+            cost = self.cost.prefill_s(bucket)
+            if self.tracer.enabled:
+                self.tracer.span(self._track, "prefill",
+                                 self.clock + elapsed, cost, cat=CAT_ENGINE,
+                                 rid=st.rid, bucket=bucket, prompt_len=plen)
+            with _span(SPAN_READ):
+                tok = int(np.argmax(np.asarray(logits)[0, -1]))
         self._emit(st, tok, self.clock + elapsed + cost)
         if st.handle.done:
             return cost
@@ -1022,7 +1065,9 @@ class Engine:
                 if self.cfg.reserve_lifetime
                 else self.budget.pages_for(plen + 1))
         phys = self.kv.alloc(st.rid, need)
-        self._write_prefill_pages(cache, phys, plen)
+        with _span(SPAN_PAGE_WRITE, rid=st.rid,
+                   pages=self.budget.pages_for(plen)):
+            self._write_prefill_pages(cache, phys, plen)
         for lp, p in enumerate(phys):
             self._table[slot, lp] = p
         st.index = plen
@@ -1041,6 +1086,7 @@ class Engine:
             lambda pool_leaf, page_leaf: pool_leaf.at[:, phys].set(
                 jnp.asarray(page_leaf, pool_leaf.dtype)),
             self._pool, payload)
+        self._pages_written += 1
 
     def _write_prefill_pages(self, cache, phys: List[int],
                              plen: int) -> None:
@@ -1120,48 +1166,51 @@ class Engine:
                    if st.ready_at <= self.clock + elapsed]
         if not running:
             return 0.0
-        for st in running:
-            self._lengths[st.slot] = st.index
-            self._slot_tok[st.slot] = st.cur_tok
-            st.last_sched = self.steps
-            if st.on_first_decode is not None:
-                # first decode of a handed-off row: report the modeled
-                # use time (>= every page's transfer completion — the
-                # transferred-before-use fact the sanitizer audits)
-                st.on_first_decode(self.clock + elapsed)
-                st.on_first_decode = None
         # gather live rows into a pow2 row bucket: pad with idle slots
         # (trash page table, length 0 — exactly what a full-array
         # decode feeds for them), so the decode batch shrinks with
         # occupancy while per-row outputs stay identical
         bucket = self._row_bucket(len(running))
-        self._row_buckets_used.add(bucket)
-        rows = [st.slot for st in running]
-        if bucket < self.cfg.max_slots:
-            idle = [i for i, s in enumerate(self._slots) if s is None]
-            sel = np.asarray(rows + idle[:bucket - len(rows)], np.int32)
-        else:
-            sel = np.arange(self.cfg.max_slots, dtype=np.int32)
-            rows = list(sel)                # full array: row == slot
-        toks = jnp.asarray(self._slot_tok[sel][:, None])
-        table = jnp.asarray(self._table[sel])
-        lengths = jnp.asarray(self._lengths[sel])
-        new_toks, self._pool = self._decode_fn(self.params, toks,
-                                               self._pool, table, lengths)
-        new_toks = np.asarray(new_toks)
-        pos = {slot: i for i, slot in enumerate(rows)}
-        cost = self.cost.decode_s(len(running))
-        at = self.clock + elapsed + cost
-        if self.tracer.enabled:
-            self.tracer.span(self._track, "decode",
-                             self.clock + elapsed, cost, cat=CAT_ENGINE,
-                             rows=len(running), bucket=bucket)
-        for st in running:
-            tok = int(new_toks[pos[st.slot], 0])
-            st.index += 1
-            st.cur_tok = tok
-            self._decoded_tokens += 1
-            self._emit(st, tok, at)
+        with _span(SPAN_DECODE, rows=len(running), bucket=bucket):
+            for st in running:
+                self._lengths[st.slot] = st.index
+                self._slot_tok[st.slot] = st.cur_tok
+                st.last_sched = self.steps
+                if st.on_first_decode is not None:
+                    # first decode of a handed-off row: report the modeled
+                    # use time (>= every page's transfer completion — the
+                    # transferred-before-use fact the sanitizer audits)
+                    st.on_first_decode(self.clock + elapsed)
+                    st.on_first_decode = None
+            self._row_buckets_used.add(bucket)
+            rows = [st.slot for st in running]
+            if bucket < self.cfg.max_slots:
+                idle = [i for i, s in enumerate(self._slots) if s is None]
+                sel = np.asarray(rows + idle[:bucket - len(rows)], np.int32)
+            else:
+                sel = np.arange(self.cfg.max_slots, dtype=np.int32)
+                rows = list(sel)                # full array: row == slot
+            toks = jnp.asarray(self._slot_tok[sel][:, None])
+            table = jnp.asarray(self._table[sel])
+            lengths = jnp.asarray(self._lengths[sel])
+            new_toks, self._pool = self._decode_fn(self.params, toks,
+                                                   self._pool, table, lengths)
+            with _span(SPAN_READ):
+                new_toks = np.asarray(new_toks)
+            pos = {slot: i for i, slot in enumerate(rows)}
+            cost = self.cost.decode_s(len(running))
+            at = self.clock + elapsed + cost
+            if self.tracer.enabled:
+                self.tracer.span(self._track, "decode",
+                                 self.clock + elapsed, cost, cat=CAT_ENGINE,
+                                 rows=len(running), bucket=bucket)
+            with _span(SPAN_EMIT, rows=len(running)):
+                for st in running:
+                    tok = int(new_toks[pos[st.slot], 0])
+                    st.index += 1
+                    st.cur_tok = tok
+                    self._decoded_tokens += 1
+                    self._emit(st, tok, at)
         return cost
 
     # ---- observability ---------------------------------------------------
@@ -1169,6 +1218,7 @@ class Engine:
     # the registry path  serve/<tenant>/<key>
     _STATS_KEYS = ("clock_s", "steps", "busy_s", "queue_depth", "running",
                    "swapped", "completed", "failed_oom", "tokens_decoded",
+                   "pages_written",
                    "throughput_tok_s", "throughput_busy_tok_s", "preempts",
                    "preempt_swaps", "preempt_recomputes", "prefill_buckets",
                    "prefill_compiles", "decode_row_buckets",
@@ -1196,6 +1246,7 @@ class Engine:
             ("failed_oom",
              sum(s is RequestStatus.FAILED_OOM for s in statuses)),
             ("tokens_decoded", self._decoded_tokens),
+            ("pages_written", self._pages_written),
             # clock_s includes idle inter-arrival gaps (advance_clock),
             # so this number is arbitrarily diluted on sparse traces —
             # it is the *offered-load* rate, kept for trace comparisons

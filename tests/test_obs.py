@@ -133,10 +133,7 @@ def test_registry_kinds_snapshot_and_tree():
     reg.counter("a/events").inc()
     reg.counter("a/events").inc(2)
     reg.set("a/b/label", "x")
-    for v in range(1, 101):
-        reg.histogram("a/lat").observe(float(v))
     assert reg.value("a/events") == 3
-    assert reg.histogram("a/lat").summary()["p95"] == 95.0
     snap = reg.snapshot("a/b/")
     assert snap == {"a/b/label": "x"}
     tree = reg.tree()
