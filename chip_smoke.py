@@ -13,7 +13,10 @@ vocab 151936) with random weights from a fixed seed:
                 (40/8, D=128, grouped query heads);
   (b) serve   — ``launch/serve.py`` engine mode, 16 synthetic requests,
                 then the engine's decode program is compiled and must
-                hold the kernel as a ``tpu_custom_call``;
+                hold the kernel as a ``tpu_custom_call``; its pool
+                alias (``Engine.decode_alias_bytes``) is reported, and
+                must equal the pool's bytes where the step donates the
+                pool (``serve.engine.donates_pool``), else 0;
   (c) train   — ``launch/train.py`` for 5 steps.
 
 ``--four-chips`` runs only the cross-chip path: ``launch/train.py`` on a
@@ -160,10 +163,12 @@ SERVE_ARGV = ["--arch", ARCH, "--requests", "16", "--max-new", "32",
 
 
 def phase_serve():
+    import jax
     from repro.configs import get_config
     from repro.launch import serve as serve_cli
     from repro.models.api import build_model
     from repro.serve import Engine, EngineConfig
+    from repro.serve.engine import donates_pool
 
     walls = []
     for run in ("cold", "warm"):
@@ -184,6 +189,8 @@ def phase_serve():
     t0 = time.perf_counter()
     hlo = engine.lower_decode().compile().as_text()
     decode_compile = time.perf_counter() - t0
+    alias = engine.decode_alias_bytes()
+    pool_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(engine._pool))
     del engine
     report("serve", requests=out["requests"], completed=st["completed"],
            failed_oom=st["failed_oom"], short_requests=out["short_requests"],
@@ -192,11 +199,16 @@ def phase_serve():
            decode_compiles=st["decode_compiles"],
            sample_tokens=out["sample_tokens"],
            decode_hlo_tpu_custom_call="tpu_custom_call" in hlo,
+           decode_alias_bytes=alias, pool_bytes=pool_bytes,
            host_wall_cold_s=walls[0], host_wall_warm_s=walls[1],
            host_decode_lower_compile_s=decode_compile,
            **device_bytes())
     check("tpu_custom_call" in hlo,
           "engine decode program holds no tpu_custom_call")
+    want = pool_bytes if donates_pool(model.cfg.head_dim) else 0
+    check(alias == want,
+          f"decode program aliases {alias} bytes, not {want}: the pool "
+          f"of {pool_bytes} bytes is not the one buffer donated")
 
 
 # ---------------------------------------------------------------------------

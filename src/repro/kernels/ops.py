@@ -52,16 +52,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 @functools.partial(jax.jit, static_argnames=("sliding_window",))
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    page_table: jax.Array, lengths: jax.Array, *,
+                    page_table: jax.Array, lengths: jax.Array,
+                    layer: jax.Array, *,
                     sliding_window: Optional[int] = None) -> jax.Array:
     """Model layout: q (B,1,H,D) single decode token per sequence;
-    k/v pages (P,ps,KV,D); page_table (B,PMAX); lengths (B,) valid KV
-    tokens (including the just-written one) -> (B,1,H,D)."""
+    k/v pages the stacked pool (L,P,ps,KV,D), read at int32 scalar
+    ``layer``; page_table (B,PMAX); lengths (B,) valid KV tokens
+    (including the just-written one) -> (B,1,H,D)."""
     B, S, H, D = q.shape
     assert S == 1, "paged attention is a decode (one-query) kernel"
-    out = _pa.paged_decode_attention(
+    out = _pa.paged_decode_attention_layered(
         q[:, 0], k_pages, v_pages, page_table, lengths,
-        sm_scale=1.0 / (D ** 0.5), sliding_window=sliding_window)
+        jnp.reshape(layer, (1,)), sm_scale=1.0 / (D ** 0.5),
+        sliding_window=sliding_window)
     return out[:, None]
 
 

@@ -22,6 +22,12 @@ Layouts (kernel-native):
                                 idle row: output is all-zeros)
   out          (B, H, D)
 
+The layered form (``paged_decode_attention_layered``) takes the whole
+stacked pool, k/v ``(L, P, ps, KV, D)``, and the layer to read as a
+third scalar-prefetch operand ``layer`` (1,) int32: the K/V index maps
+resolve ``(layer, physical page)`` directly, so a decode that carries
+the pool through its layer loop never slices a layer out of it.
+
 Grid: (B, PMAX) with the page dimension sequential ("arbitrary") —
 online-softmax state persists across pages in fp32 VMEM scratch exactly
 like the flash kernel.  The page table and the lengths ride in as
@@ -57,9 +63,10 @@ _OTHER_GROUP = 1 << 30      # token offset of a row outside the head's group
 _F32 = jax.lax.Precision.HIGHEST   # fp32 matmul math, as in kernels/ref.py
 
 
-def _kernel(pt_ref, len_ref, tok_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, sm_scale: float, page_size: int,
+def _kernel(pt_ref, len_ref, layer_ref, tok_ref, q_ref, k_ref, v_ref,
+            o_ref, m_ref, l_ref, acc_ref, *, sm_scale: float, page_size: int,
             n_pages_max: int, sliding_window: Optional[int]):
+    del layer_ref                              # read by the index maps only
     b = pl.program_id(0)
     j = pl.program_id(1)                       # logical page (sequential)
 
@@ -123,13 +130,31 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            sliding_window: Optional[int] = None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """q (B,H,D); k/v pages (P,ps,KV,D); page_table (B,PMAX) int32;
-    lengths (B,) int32 -> (B,H,D)."""
+    lengths (B,) int32 -> (B,H,D).  One layer's pool: the layered form
+    over a layer axis of 1."""
+    return paged_decode_attention_layered(
+        q, k_pages[None], v_pages[None], page_table, lengths,
+        jnp.zeros((1,), jnp.int32), sm_scale=sm_scale,
+        sliding_window=sliding_window, interpret=interpret)
+
+
+def paged_decode_attention_layered(q: jax.Array, k_pages: jax.Array,
+                                   v_pages: jax.Array, page_table: jax.Array,
+                                   lengths: jax.Array, layer: jax.Array, *,
+                                   sm_scale: Optional[float] = None,
+                                   sliding_window: Optional[int] = None,
+                                   interpret: Optional[bool] = None,
+                                   ) -> jax.Array:
+    """q (B,H,D); k/v pages (L,P,ps,KV,D), the stacked pool; page_table
+    (B,PMAX) int32; lengths (B,) int32; layer (1,) int32, the pool layer
+    to read -> (B,H,D)."""
     B, H, D = q.shape
-    P, ps, KV, _ = k_pages.shape
+    L, P, ps, KV, _ = k_pages.shape
     PMAX = page_table.shape[1]
     assert H % KV == 0, (H, KV)
     assert v_pages.shape == k_pages.shape
     assert page_table.shape[0] == B and lengths.shape == (B,)
+    assert layer.shape == (1,), layer.shape
     if sm_scale is None:
         sm_scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -140,16 +165,18 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         sliding_window=sliding_window)
 
     rows = ps * KV
+    page = pl.BlockSpec((None, 1, rows, D),
+                        lambda b, j, pt, ln, lyr: (lyr[0], pt[b, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # page_table, lengths
+        num_scalar_prefetch=3,                 # page_table, lengths, layer
         grid=(B, PMAX),
         in_specs=[
-            pl.BlockSpec((H, rows), lambda b, j, pt, ln: (0, 0)),
-            pl.BlockSpec((1, H, D), lambda b, j, pt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, rows, D), lambda b, j, pt, ln: (pt[b, j], 0, 0)),
-            pl.BlockSpec((1, rows, D), lambda b, j, pt, ln: (pt[b, j], 0, 0)),
+            pl.BlockSpec((H, rows), lambda b, j, pt, ln, lyr: (0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, j, pt, ln, lyr: (b, 0, 0)),
+            page,
+            page,
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, j, pt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, j, pt, ln, lyr: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -164,5 +191,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table, lengths, _token_offsets(H, KV, ps), q,
-      k_pages.reshape(P, rows, D), v_pages.reshape(P, rows, D))
+    )(page_table, lengths, layer.astype(jnp.int32),
+      _token_offsets(H, KV, ps), q,
+      k_pages.reshape(L, P, rows, D), v_pages.reshape(L, P, rows, D))

@@ -284,38 +284,42 @@ def attention_fwd(params, x: jax.Array, cfg: AttentionConfig, *,
 def attention_fwd_paged(params, x: jax.Array, cfg: AttentionConfig, *,
                         positions: jax.Array,
                         k_pages: jax.Array, v_pages: jax.Array,
+                        layer: jax.Array,
                         page_table: jax.Array, lengths: jax.Array,
                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Decode attention over a *paged* KV pool (one layer's pages).
+    """Decode attention over the *paged* KV pool, at one layer.
 
-    x: (B, 1, d) — one new token per sequence.  k/v pages: (P, ps, KV, hd),
-    the shared physical page pool for this layer.  page_table: (B, PMAX)
-    int32 logical->physical ids.  lengths: (B,) current KV length per
-    sequence — also the write position of this token (idle rows carry
-    length 0 and a page table full of trash-page ids; their writes land
-    in the trash page and their output is ignored by the caller).
+    x: (B, 1, d) — one new token per sequence.  k/v pages: (L, P, ps, KV,
+    hd), the whole stacked physical page pool; layer: int32 scalar, the
+    layer this call reads and writes.  page_table: (B, PMAX) int32
+    logical->physical ids.  lengths: (B,) current KV length per sequence
+    — also the write position of this token (idle rows carry length 0
+    and a page table full of trash-page ids; their writes land in the
+    trash page and their output is ignored by the caller).
 
-    The new token's K/V is scattered into each row's current page, then
-    the Pallas kernel gathers the whole prefix through the page table.
-    Returns (out (B,1,d), k_pages, v_pages).
+    The new token's K/V is scattered into each row's current page of
+    ``layer`` (one in-place ``.at[layer, phys, off].set`` a leaf), then
+    the Pallas kernel gathers the whole prefix through the page table
+    straight out of the stacked pool: no layer is sliced out or written
+    back.  Returns (out (B,1,d), k_pages, v_pages).
     """
     from repro.kernels.ops import paged_attention
 
     B, S, _ = x.shape
     assert S == 1, "paged attention serves decode (one token per step)"
     H, hd = cfg.n_heads, cfg.head_dim
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
 
     q, k, v = project_qkv(params, x, cfg, positions=positions)
 
     # scatter this token's K/V into each row's current physical page
     phys = page_table[jnp.arange(B), lengths // ps]        # (B,)
     off = lengths % ps
-    k_pages = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, phys, off].set(k[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, phys, off].set(v[:, 0].astype(v_pages.dtype))
 
     out = paged_attention(q, k_pages, v_pages, page_table, lengths + 1,
-                          sliding_window=cfg.sliding_window)
+                          layer, sliding_window=cfg.sliding_window)
     out = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * hd), params["wo"])
     return out, k_pages, v_pages
 

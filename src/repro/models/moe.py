@@ -16,6 +16,7 @@ Two expert sharding layouts (per-arch choice, see DESIGN.md):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -271,27 +272,27 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, cache_index, *,
     return logits, new_cache
 
 
+def block_fwd_paged(params, x, cfg: ModelConfig, *, groups: int = 1,
+                    **paged):
+    """``block_fwd`` for decode over the stacked paged KV pool; ``paged``
+    is ``transformer.block_fwd_paged``'s pool keywords."""
+    h = L.apply_norm(x, params["norm1"], cfg.norm_type)
+    attn_out, k_pages, v_pages = L.attention_fwd_paged(
+        params["attn"], h, T.attn_config(cfg), **paged)
+    x = x + attn_out
+    h2 = L.apply_norm(x, params["norm2"], cfg.norm_type)
+    moe_out, _ = moe_mlp_fwd(params["moe"], h2, cfg, groups=groups)
+    return x + moe_out, k_pages, v_pages
+
+
 def decode_paged(params, cfg: ModelConfig, tokens, pools, page_table,
                  lengths, *, groups: int = 1):
     """One-token decode over the shared paged KV pool (see
-    ``transformer.decode_paged``); MoE blocks, same page mechanics."""
+    ``transformer.decode_paged``); MoE blocks, the same carried pool."""
     params = T.cast_params(params, cfg)
     x = T._embed_inputs(params, cfg, {"tokens": tokens})
-    positions = lengths[:, None].astype(jnp.int32)
-
-    def body(x, scanned):
-        layer_params, kp, vp = scanned
-        h = L.apply_norm(x, layer_params["norm1"], cfg.norm_type)
-        attn_out, kp, vp = L.attention_fwd_paged(
-            layer_params["attn"], h, T.attn_config(cfg), positions=positions,
-            k_pages=kp, v_pages=vp, page_table=page_table, lengths=lengths)
-        x = x + attn_out
-        h2 = L.apply_norm(x, layer_params["norm2"], cfg.norm_type)
-        moe_out, _ = moe_mlp_fwd(layer_params["moe"], h2, cfg, groups=groups)
-        x = x + moe_out
-        return x, (kp, vp)
-
-    x, (nk, nv) = lax.scan(body, x, (params["layers"], pools["k"],
-                                     pools["v"]), unroll=scan_unroll())
+    x, pools = T.scan_layers_paged(
+        functools.partial(block_fwd_paged, cfg=cfg, groups=groups),
+        params["layers"], x, pools, page_table, lengths)
     x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
-    return L.unembed(params["embedding"], x, cfg.vocab), {"k": nk, "v": nv}
+    return L.unembed(params["embedding"], x, cfg.vocab), pools

@@ -89,14 +89,15 @@ def block_fwd(params, x: jax.Array, cfg: ModelConfig, *,
 
 def block_fwd_paged(params, x: jax.Array, cfg: ModelConfig, *,
                     positions: jax.Array, k_pages: jax.Array,
-                    v_pages: jax.Array, page_table: jax.Array,
-                    lengths: jax.Array):
-    """``block_fwd`` for decode over a paged KV pool (one token/row)."""
+                    v_pages: jax.Array, layer: jax.Array,
+                    page_table: jax.Array, lengths: jax.Array):
+    """``block_fwd`` for decode over the stacked paged KV pool at
+    ``layer`` (one token/row)."""
     acfg = attn_config(cfg)
     h = L.apply_norm(x, params["norm1"], cfg.norm_type)
     attn_out, k_pages, v_pages = L.attention_fwd_paged(
         params["attn"], h, acfg, positions=positions,
-        k_pages=k_pages, v_pages=v_pages,
+        k_pages=k_pages, v_pages=v_pages, layer=layer,
         page_table=page_table, lengths=lengths)
     if cfg.parallel_block:
         mlp_out = L.mlp_fwd(params["mlp"], h, mlp_config(cfg))
@@ -257,6 +258,38 @@ def decode_step(params, cfg: ModelConfig, tokens: jax.Array,
     return logits, new_cache
 
 
+def scan_layers_paged(block, layers, x: jax.Array,
+                      pools: Dict[str, jax.Array], page_table: jax.Array,
+                      lengths: jax.Array,
+                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Run ``block`` over the stacked ``layers`` for one decode token.
+
+    The pool rides the layer loop as scan *carry* next to the layer
+    index, so each layer writes its token into the one stacked pool and
+    the kernel reads that layer in place: no layer of the pool is sliced
+    out or stacked back, and under a jit that donates the pool the whole
+    step updates it in place.  ``block(layer_params, x, positions=,
+    k_pages=, v_pages=, layer=, page_table=, lengths=) -> (x, k, v)``.
+    """
+    positions = lengths[:, None].astype(jnp.int32)          # (B, 1)
+    paged = dict(positions=positions, page_table=page_table,
+                 lengths=lengths)
+
+    def body(carry, scanned):
+        x, kp, vp = carry
+        layer_params, layer = scanned
+        x, kp, vp = block(layer_params, x, k_pages=kp, v_pages=vp,
+                          layer=layer, **paged)
+        return (x, kp, vp), None
+
+    n_layers = pools["k"].shape[0]
+    (x, nk, nv), _ = lax.scan(
+        body, (x, pools["k"], pools["v"]),
+        (layers, jnp.arange(n_layers, dtype=jnp.int32)),
+        unroll=scan_unroll())
+    return x, {"k": nk, "v": nv}
+
+
 def decode_paged(params, cfg: ModelConfig, tokens: jax.Array,
                  pools: Dict[str, jax.Array], page_table: jax.Array,
                  lengths: jax.Array,
@@ -271,17 +304,8 @@ def decode_paged(params, cfg: ModelConfig, tokens: jax.Array,
     """
     params = cast_params(params, cfg)
     x = _embed_inputs(params, cfg, {"tokens": tokens})
-    positions = lengths[:, None].astype(jnp.int32)          # (B, 1)
-
-    def body(x, scanned):
-        layer_params, kp, vp = scanned
-        x, kp, vp = block_fwd_paged(layer_params, x, cfg,
-                                    positions=positions,
-                                    k_pages=kp, v_pages=vp,
-                                    page_table=page_table, lengths=lengths)
-        return x, (kp, vp)
-
-    x, (nk, nv) = lax.scan(body, x, (params["layers"], pools["k"],
-                                     pools["v"]), unroll=scan_unroll())
+    x, pools = scan_layers_paged(
+        partial(block_fwd_paged, cfg=cfg), params["layers"], x,
+        pools, page_table, lengths)
     x = L.apply_norm(x, params["final_norm"], cfg.norm_type)
-    return logits_fn(params, cfg, x), {"k": nk, "v": nv}
+    return logits_fn(params, cfg, x), pools

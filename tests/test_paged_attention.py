@@ -8,7 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import (paged_decode_attention,
+                                           paged_decode_attention_layered)
 from repro.kernels.ops import paged_attention
 from repro.kernels.ref import paged_attention_ref
 
@@ -49,15 +50,32 @@ def test_kernel_matches_ref_across_dtypes(dtype, atol):
                         atol=atol, rtol=atol)
 
 
-def test_kernel_gqa_and_sliding_window():
+@pytest.mark.parametrize("layer", [None, 0, 1, 2],
+                         ids=["one-layer", "layer0", "layer1", "layer2"])
+def test_kernel_gqa_and_sliding_window(layer):
+    """GQA and the sliding window; with ``layer`` set, the layered kernel
+    reads that layer of a stacked 3-layer pool and must equal the 4-D
+    kernel on that layer's slice bit for bit."""
     q, kp, vp, pt, lengths = _case(2, B=3, KV=2, G=4, D=16, P=8, ps=8,
                                    PMAX=4, dtype=jnp.float32)
+    if layer is not None:
+        rng = np.random.RandomState(7)
+        k_stack = jnp.asarray(rng.standard_normal((3,) + kp.shape),
+                              jnp.float32).at[layer].set(kp)
+        v_stack = jnp.asarray(rng.standard_normal((3,) + vp.shape),
+                              jnp.float32).at[layer].set(vp)
     for win in (None, 10):
         got = paged_decode_attention(q, kp, vp, pt, lengths,
                                      sliding_window=win)
         want = paged_attention_ref(q, kp, vp, pt, lengths,
                                    sliding_window=win)
         assert jnp.allclose(got, want, atol=1e-5, rtol=1e-5)
+        if layer is not None:
+            layered = paged_decode_attention_layered(
+                q, k_stack, v_stack, pt, lengths,
+                jnp.asarray([layer], jnp.int32), sliding_window=win)
+            np.testing.assert_array_equal(np.asarray(layered),
+                                          np.asarray(got))
 
 
 def test_kernel_zero_length_rows_emit_zeros():
@@ -129,10 +147,12 @@ def test_kernel_matches_dense_softmax():
 
 
 def test_ops_wrapper_model_layout():
-    """ops.paged_attention takes/returns the model's (B, 1, H, D)."""
+    """ops.paged_attention takes/returns the model's (B, 1, H, D), over
+    the stacked pool read at a layer."""
     q, kp, vp, pt, lengths = _case(6, B=3, KV=2, G=2, D=8, P=6, ps=4,
                                    PMAX=3, dtype=jnp.float32)
-    out = paged_attention(q[:, None], kp, vp, pt, lengths)
+    out = paged_attention(q[:, None], kp[None], vp[None], pt, lengths,
+                          jnp.int32(0))
     assert out.shape == (3, 1, 4, 8)
     want = paged_attention_ref(q, kp, vp, pt, lengths)
     assert jnp.allclose(out[:, 0], want, atol=1e-5, rtol=1e-5)
