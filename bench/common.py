@@ -1,6 +1,20 @@
 """What every cell shares: finding a cell's files by name, building the
 model configuration a configuration file states, the device check, the
-peaks table and the result line."""
+peaks table and the result line.
+
+A configuration file holds published keys and the keys the harness
+reads (``HARNESS_KEYS``), and nothing else.  Every published key is
+applied to a ``ModelConfig`` field: through ``HF_KEYS``, or, for a key
+that ``HF_KEYS`` does not name, through the file's own ``map`` (published
+key -> field).  ``program`` holds ``ModelConfig`` fields that no
+published key states, such as the experts one chip holds, and may not
+set a field that a published key or the serving dtypes set.
+``reference`` and ``work`` name, relative to the benchmark's root, the
+module that checks the served tokens (``served_gaps(params, cfg, served,
+pad_to, control=False)``) and the module that counts the work the
+readers hold device time against (``prefill_flops(cfg, n)``,
+``decode_flops(cfg, n)`` and ``paged_attention_work(cfg, lengths,
+page_size, kv_itemsize)``, the last over all layers)."""
 
 from __future__ import annotations
 
@@ -28,6 +42,10 @@ HF_KEYS = {
     "rms_norm_eps": "norm_eps",
     "tie_word_embeddings": "tie_embeddings",
 }
+# keys of a configuration file that the harness reads or that document it
+HARNESS_KEYS = frozenset({"arch", "smoke", "source", "deployment",
+                          "reference", "work", "reduced", "published",
+                          "assumed", "serve", "program", "map"})
 
 
 class BenchError(RuntimeError):
@@ -50,6 +68,11 @@ class Cell:
     def chips(self) -> int:
         return int(self.workload["chips"])
 
+    def module(self, key: str):
+        """The module that the configuration file names under ``key``
+        (``reference`` or ``work``), from the benchmark's root."""
+        return load_module(self.root / self.config[key])
+
 
 def _applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
@@ -65,6 +88,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    _check_keys(config)
     traffic = json.loads(
         (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
     return Cell(name=name, workload=w, config=config, traffic=traffic,
@@ -73,29 +97,64 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 root=root)
 
 
+def _check_keys(config: Dict[str, Any]) -> None:
+    """Every key of a configuration file is one the harness applies or
+    reads: a key that neither ``HF_KEYS`` nor the file's ``map`` maps and
+    that is not a harness key is an error, and so is a ``map`` entry that
+    is not such a key of the file, and a file that names no reference or
+    no work module."""
+    arch = config.get("arch")
+    own = config.get("map", {})
+    bad = sorted(k for k in own
+                 if k not in config or k in HF_KEYS or k in HARNESS_KEYS)
+    if bad:
+        raise BenchError(f"{arch}: 'map' names {bad}, which are not keys "
+                         f"of the file that HF_KEYS leaves unmapped")
+    unknown = sorted(set(config) - HARNESS_KEYS - set(HF_KEYS) - set(own))
+    if unknown:
+        raise BenchError(f"{arch}: configuration keys {unknown} are "
+                         f"neither mapped published keys nor harness keys")
+    missing = [k for k in ("reference", "work") if k not in config]
+    if missing:
+        raise BenchError(f"{arch}: the configuration file names no "
+                         f"{' and no '.join(missing)} module")
+
+
 def model_config(config: Dict[str, Any]):
     """The ``repro`` ModelConfig that a configuration file states: the
-    registry's architecture with the file's published keys and its
-    serving dtypes applied.  A key the program cannot honour is an
-    error."""
+    registry's architecture with the file's published keys, its serving
+    dtypes and then its ``program`` fields applied.  A key the harness
+    would not apply, a field set twice and a field that ``ModelConfig``
+    lacks are errors."""
     from repro.configs import get_config
-    base = get_config(config["arch"], smoke=bool(config.get("smoke", False)))
-    changes = {field: config[key] for key, field in HF_KEYS.items()
-               if key in config}
+    _check_keys(config)
+    arch = config["arch"]
+    base = get_config(arch, smoke=bool(config.get("smoke", False)))
+    keys = {**HF_KEYS, **config.get("map", {})}
+    targets = [keys[k] for k in config if k in keys]
+    twice = sorted({f for f in targets if targets.count(f) > 1})
+    if twice:
+        raise BenchError(f"{arch}: published keys set the fields {twice} "
+                         f"more than once")
+    program = config.get("program", {})
+    fields = {f.name for f in dataclasses.fields(base)}
+    unknown = sorted((set(targets) | set(program)) - fields)
+    if unknown:
+        raise BenchError(f"{arch}: {unknown} are not ModelConfig fields")
+    changes = {keys[k]: config[k] for k in config if k in keys}
     changes.update(config["serve"]["dtypes"])
-    cfg = dataclasses.replace(base, **changes)
-    if not cfg.tie_embeddings:
-        # the dense transformer always unembeds with its embedding table
-        raise BenchError(f"{config['arch']}: the program ties the output "
-                         f"head to the embedding and cannot run an untied "
-                         f"model")
-    return cfg
+    overridden = sorted(set(program) & set(changes))
+    if overridden:
+        raise BenchError(f"{arch}: program sets {overridden}, which the "
+                         f"published keys or the serving dtypes set")
+    changes.update(program)
+    return dataclasses.replace(base, **changes)
 
 
 def load_module(path: Path):
     """Import a file by path (metric files carry dots in their names)."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        "bench_file_" + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod

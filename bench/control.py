@@ -1,8 +1,9 @@
 """Readings that set a serving cell's limit: for each seed, the widest
 logit gap of the tokens the program served, and of the tokens that the
-fp8 control (the reference one precision step below bf16) puts first at
-the same positions.  One process, one engine, a short window per seed at
-the cell's own load; the benchmark's own runs never run the control.
+control puts first at the same positions (the control is the reference
+that the configuration file names, one precision step below the one
+served).  One process, one engine, a short window per seed at the cell's
+own load; the benchmark's own runs never run the control.
 
     python bench/control.py --workload <cell> --seeds 1,2,3 --seconds 20
 """
@@ -19,10 +20,11 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import common, reference, serve  # noqa: E402
+from bench import common, serve  # noqa: E402
 
 
 def readings(cell, seeds, seconds: float):
+    reference = cell.module("reference")
     engine, mcfg, params = serve.build(cell, seeds[0])
     serve.warm_up(engine, cell)
     check = cell.config["serve"]["check"]

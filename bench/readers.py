@@ -5,16 +5,19 @@ of these).  A reduction that finds nothing to read returns None.
 Serving records hold per request its due time and its token times, and
 per step its decode rows, the key count of each decoded row and the
 prompt length of each prefill, all in seconds from the window's start
-on the host clock.  A ``--trace 1`` run adds ``trace`` (from
-``bench.trace.reduce``) and ``traced``: the steps and host seconds that
-the profiled span covered.
+on the host clock; the engine's counters over the window
+(``counters``); the model configuration (``model``) and the work module
+its configuration file names (``work``), which counts the flops and bytes
+that the device-trace readers hold device time against.  A ``--trace 1``
+run adds ``trace`` (from ``bench.trace.reduce``, with the engine's
+``host_spans`` from ``bench.spans``) and ``traced``: the steps and host
+seconds that the profiled span covered.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
-from bench import work
 from bench.common import quantile
 
 KERNEL = "paged_decode_attention"     # the Pallas kernel's name
@@ -97,6 +100,15 @@ def decode_step_ms(rec: Record) -> Optional[float]:
     return 1e3 * sum(p["seconds"] for p in progs) / calls if calls else None
 
 
+def roofline_share(flops: float, nbytes: float, seconds: float,
+                   peak_flops: float, peak_bw: float):
+    """(percent of the roofline reached, bound): the least time the chip
+    could take over the time taken."""
+    t_flops, t_bytes = flops / peak_flops, nbytes / peak_bw
+    bound = "compute" if t_flops >= t_bytes else "memory"
+    return 100.0 * max(t_flops, t_bytes) / seconds, bound
+
+
 def _traced_steps(rec: Record) -> List[Dict[str, Any]]:
     a, b = rec["traced"]["steps"]
     return rec["steps"][a:b]
@@ -112,16 +124,16 @@ def paged_attn_roofline(rec: Record) -> Optional[float]:
     secs = sum(s for k, s in chip["ops_s"].items() if KERNEL in k)
     if secs <= 0:
         return None
-    cfg = rec["model"]
+    cfg, work = rec["model"], rec["work"]
     flops = nbytes = 0
     for s in _traced_steps(rec):
         f, b = work.paged_attention_work(cfg, s["lengths"], rec["page_size"],
                                          rec["kv_itemsize"])
-        flops += f * cfg.n_layers
-        nbytes += b * cfg.n_layers
+        flops += f
+        nbytes += b
     pk = rec["peaks"]
-    share, _ = work.roofline_share(flops, nbytes, secs, pk["bf16_flops_per_s"],
-                                   pk["hbm_bytes_per_s"])
+    share, _ = roofline_share(flops, nbytes, secs, pk["bf16_flops_per_s"],
+                              pk["hbm_bytes_per_s"])
     return share
 
 
@@ -140,7 +152,7 @@ def mfu(rec: Record) -> Optional[float]:
     tr = rec.get("trace")
     if not tr:
         return None
-    cfg = rec["model"]
+    cfg, work = rec["model"], rec["work"]
     steps = _traced_steps(rec)
     flops = sum(sum(work.prefill_flops(cfg, n) for n in s["prefills"])
                 + sum(work.decode_flops(cfg, n) for n in s["lengths"])

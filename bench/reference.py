@@ -7,8 +7,13 @@ where ``qkv_bias``; per-head RMSNorm of q and k where ``qk_norm``
 (Qwen3); rotary embedding on the two halves of each head (HF
 ``rotate_half``); grouped-query attention, query head ``h`` reading KV
 head ``h // (H / KV)``; causal softmax; SwiGLU MLP; final RMSNorm; the
-head is the embedding table (``tie_word_embeddings``).  It runs one
-layer at a time from the stacked weights, so that it fits beside them.
+head is the embedding table (``tie_word_embeddings``), so an untied
+configuration is refused.  It runs one layer at a time from the stacked
+weights, so that it fits beside them.
+
+``served_gaps`` is the contract a configuration file's ``reference``
+module meets.  A reference for a variant of these decoders may call it
+with its own ``mask``: which keys each query position reads.
 
 ``quant="fp8"`` is the control: every matmul operand is rounded to
 float8 e4m3 with a per-tensor (weights) or per-row (activations) scale,
@@ -23,6 +28,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.common import BenchError
 
 HI = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
@@ -60,8 +67,13 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(q, k, v, quant):
-    """Causal GQA. q (S, H, D); k, v (S, KV, D) -> (S, H, D)."""
+def causal(rows, cols, cfg):
+    """Query position ``rows[:, None]`` reads key ``cols[None, :]``."""
+    return cols[None, :] <= rows[:, None]
+
+
+def attention(q, k, v, quant, cfg, mask=causal):
+    """GQA under ``mask``. q (S, H, D); k, v (S, KV, D) -> (S, H, D)."""
     S, H, D = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -76,8 +88,7 @@ def attention(q, k, v, quant):
         s = jnp.einsum("qhd,khd->hqk", _op(qb, quant), kq,
                        precision=HI) / jnp.sqrt(F32(D))
         rows = i * QBLOCK + jnp.arange(QBLOCK)
-        mask = jnp.arange(S)[None, :] <= rows[:, None]
-        s = jnp.where(mask[None], s, -jnp.inf)
+        s = jnp.where(mask(rows, jnp.arange(S), cfg)[None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("hqk,khd->qhd", _op(p, quant), vq, precision=HI)
 
@@ -85,7 +96,7 @@ def attention(q, k, v, quant):
     return out.reshape(n_blocks * QBLOCK, H, D)[:S]
 
 
-def block(w, x, cfg, quant=None):
+def block(w, x, cfg, quant=None, mask=causal):
     """One decoder layer over ``x`` (S, d) with float32 weights ``w``."""
     S = x.shape[0]
     H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -100,7 +111,7 @@ def block(w, x, cfg, quant=None):
         q = rmsnorm(q, a["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, a["k_norm"], cfg.norm_eps)
     q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
-    o = attention(q, k, v, quant).reshape(S, H * D)
+    o = attention(q, k, v, quant, cfg, mask).reshape(S, H * D)
     x = x + _mm(o, a["wo"], quant)
     m = w["mlp"]
     h = rmsnorm(x, w["norm2"]["scale"], cfg.norm_eps)
@@ -108,11 +119,11 @@ def block(w, x, cfg, quant=None):
     return x + _mm(act, m["w_down"], quant)
 
 
-@partial(jax.jit, static_argnames=("cfg", "quant"))
-def layer(layers, i, x, cfg, quant):
+@partial(jax.jit, static_argnames=("cfg", "quant", "mask"))
+def layer(layers, i, x, cfg, quant, mask=causal):
     """Layer ``i`` of the stacked weights, upcast to float32 here."""
     return block(jax.tree.map(lambda a: a[i].astype(F32), layers), x, cfg,
-                 quant)
+                 quant, mask)
 
 
 @jax.jit
@@ -135,7 +146,8 @@ def head(params, x, rows, cfg, quant):
 
 
 def logits(params, cfg, tokens: Sequence[int], rows: Sequence[int],
-           quant: Optional[str] = None, pad_to: Optional[int] = None):
+           quant: Optional[str] = None, pad_to: Optional[int] = None,
+           mask=causal):
     """Reference logits at positions ``rows`` of the sequence ``tokens``
     (padded at the end to ``pad_to``, which causality leaves unread)."""
     n = len(tokens)
@@ -144,26 +156,32 @@ def logits(params, cfg, tokens: Sequence[int], rows: Sequence[int],
     toks[:n] = tokens
     x = embed(params["embedding"]["table"], jnp.asarray(toks))
     for i in range(cfg.n_layers):
-        x = layer(params["layers"], jnp.int32(i), x, cfg, quant)
+        x = layer(params["layers"], jnp.int32(i), x, cfg, quant, mask)
     return head(params, x, jnp.asarray(np.asarray(rows, np.int32)), cfg,
                 quant)
 
 
-def served_gaps(params, cfg, served, pad_to: int, control: bool = False):
+def served_gaps(params, cfg, served, pad_to: int, control: bool = False,
+                mask=causal):
     """For each (prompt, tokens) served, the widest gap by which a served
     token's reference logit lies below the reference's best.  With
     ``control``, also the same gap for the token that the fp8 control
     puts first at each of those positions (the control's reading)."""
+    if not cfg.tie_embeddings:
+        raise BenchError(f"{cfg.name}: bench/reference.py reads the "
+                         f"embedding table as the head and cannot check an "
+                         f"untied model")
     prog, ctrl = [], []
     for prompt, toks in served:
         seq = list(prompt) + list(toks[:-1])
         rows = np.arange(len(prompt) - 1, len(seq))
-        ref = logits(params, cfg, seq, rows, pad_to=pad_to)
+        ref = logits(params, cfg, seq, rows, pad_to=pad_to, mask=mask)
         best = jnp.max(ref, axis=-1)
         got = jnp.take_along_axis(ref, jnp.asarray(toks)[:, None], -1)[:, 0]
         prog.append(float(jnp.max(best - got)))
         if control:
-            low = logits(params, cfg, seq, rows, quant="fp8", pad_to=pad_to)
+            low = logits(params, cfg, seq, rows, quant="fp8", pad_to=pad_to,
+                         mask=mask)
             pick = jnp.take_along_axis(ref, jnp.argmax(low, -1)[:, None], -1)
             ctrl.append(float(jnp.max(best - pick[:, 0])))
     return (prog, ctrl) if control else prog

@@ -82,8 +82,10 @@ def run(args, root: Path = common.ROOT, require_chip: bool = True,
 
 
 def _read_trace(rec, tracer, out_dir):
-    from bench import trace
-    rec["trace"] = trace.reduce(trace.extract(str(out_dir)))
+    from bench import spans, trace
+    ext = trace.extract(str(out_dir))
+    rec["trace"] = trace.reduce(ext)
+    rec["trace"]["host_spans"] = spans.host_spans(ext)
     rec["traced"] = {"steps": tracer.steps,
                      "seconds": tracer.host[1] - tracer.host[0]}
     shutil.rmtree(out_dir, ignore_errors=True)

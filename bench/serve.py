@@ -1,7 +1,9 @@
 """Serving cells: the ``repro.serve`` engine, driven by ``Engine.submit``
 and ``Engine.step`` on the host clock.
 
-The engine's own clocks are modeled seconds and are never read here.
+The engine's own clocks are modeled seconds and are never read here; its
+monotone counters (``Engine.metrics()``) are read once before and once
+after the window, and the record carries their difference.
 ``Engine.step`` ends on host reads of the tokens it produced, so the host
 time just after it returns is when those tokens exist: every token is
 stamped with it.  An open loop submits each request when it is due; a
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import common, generators as gen, reference
+from bench import common, generators as gen
 from bench.weights import make_params
 
 
@@ -69,6 +71,28 @@ def warm_up(engine, cell: common.Cell) -> Dict[str, Any]:
     return {"prefill_buckets": prefill, "decode_row_buckets": rows}
 
 
+# Engine.metrics() entries that only grow, besides those its registry
+# keeps as counters; the modeled clocks (clock_s, busy_s and the rates
+# made from them) and the gauges (queue_depth, running, ...) are not
+COUNTERS = frozenset({"steps", "completed", "failed_oom", "tokens_decoded",
+                      "pages_written", "preempts", "preempt_swaps",
+                      "preempt_recomputes"})
+
+
+def _counters(engine) -> Dict[str, float]:
+    """The engine's monotone counters: those of ``Engine.metrics()``
+    named in ``COUNTERS`` and those its registry keeps as ``Counter``s,
+    named without the engine's ``serve/<tenant>/`` prefix."""
+    from repro.obs.metrics import Counter
+    reg = engine.metrics()
+    out = {}
+    for name, value in reg.snapshot().items():
+        short = name.split("/", 2)[-1]
+        if short in COUNTERS or isinstance(reg._metrics[name], Counter):
+            out[short] = value
+    return out
+
+
 def _stamp(live: List[_Live], t: float):
     """Give new tokens the time ``t``.  Returns the requests now done, the
     prompt length of each prefill this step ran (its token 1) and the key
@@ -98,6 +122,7 @@ def run_window(engine, cell: common.Cell, seed: int, seconds: float,
     steps: List[Dict[str, Any]] = []
     requests: List[_Live] = []
     live: List[_Live] = []
+    before = _counters(engine)
     t0 = time.perf_counter()
     end = t0 + seconds
 
@@ -147,14 +172,19 @@ def run_window(engine, cell: common.Cell, seed: int, seconds: float,
                 submit(next(clients[r.client]), t, r.client)
     if tracer is not None:
         tracer.stop(len(steps))
+    after = _counters(engine)
     return {"t0": t0, "end": end, "window_s": seconds, "steps": steps,
-            "requests": requests}
+            "requests": requests,
+            "counters": {k: after[k] - before[k] for k in after
+                         if k in before}}
 
 
 def summarize(run: Dict[str, Any], mcfg, cell: common.Cell) -> Dict[str, Any]:
     """The run record: per request its due time, first-token time and
     token times; per step its rows; all on the host clock relative to
-    the window's start."""
+    the window's start.  Also the engine's counters over the window, the
+    model configuration, and the work module that the configuration
+    file names."""
     t0, end = run["t0"], run["end"]
     reqs = []
     for r in run["requests"]:
@@ -167,7 +197,8 @@ def summarize(run: Dict[str, Any], mcfg, cell: common.Cell) -> Dict[str, Any]:
               "lengths": s["lengths"]}
              for s in run["steps"]]
     return {"window_s": run["window_s"], "requests": reqs,
-            "steps": steps, "model": mcfg,
+            "steps": steps, "counters": run["counters"], "model": mcfg,
+            "work": cell.module("work"),
             "page_size": int(cell.config["serve"]["engine"]["page_size"]),
             "kv_itemsize": jnp.dtype(cell.config["serve"]["engine"]["cache_dtype"]).itemsize}
 
@@ -193,14 +224,16 @@ def sample_finished(run: Dict[str, Any], seed: int, min_tokens: int):
 
 
 def check(params, mcfg, cell: common.Cell, served) -> List[Dict[str, Any]]:
-    """Served tokens against the float32 reference: the widest gap by
-    which a served token's reference logit lies below the best."""
+    """Served tokens against the reference that the configuration file
+    names: the widest gap by which a served token's reference logit lies
+    below the best."""
     limit = float(cell.config["serve"]["check"]["max_logit_gap"])
     if not served:
         return [{"name": "finished_requests", "value": 0, "limit": 1,
                  "ok": False}]
-    gaps = reference.served_gaps(params, mcfg, served,
-                                 pad_to=int(cell.config["serve"]["engine"]["max_seq"]))
+    gaps = cell.module("reference").served_gaps(
+        params, mcfg, served,
+        pad_to=int(cell.config["serve"]["engine"]["max_seq"]))
     worst = max(gaps)
     tokens = sum(len(t) for _, t in served)
     return [{"name": "served_tokens_checked", "value": tokens, "limit": 1,

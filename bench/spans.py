@@ -7,11 +7,11 @@ profiler's clock, the clock of the device planes
 nanosecond of chip 0 inside ``bench.window`` down to the innermost
 engine span over it, or to none.
 
-``page_write_ms`` reads only what ``bench/serve.py`` records already.
-The other readers take a run record that also holds per step ``n`` (the
-engine's ``steps`` before it), per request ``admit_step``,
-``trace.host_spans`` and ``setup_jit_s``; where those are absent they
-return None.
+``page_write_ms`` and ``step_idle_ms`` read only what a ``--trace 1``
+record holds already (``bench/run.py`` adds ``trace.host_spans``).  The
+other readers take a run record that also holds per step ``n`` (the
+engine's ``steps`` before it), per request ``admit_step`` and
+``setup_jit_s``; where those are absent they return None.
 """
 
 from __future__ import annotations

@@ -1,4 +1,6 @@
-"""Operations and bytes that the algorithm needs, from shapes alone.
+"""Operations and bytes that the algorithm needs, from shapes alone: the
+work module of the dense decoders (Qwen1.5, Qwen3), whose layers all
+attend over every key.
 
 These count the work a correct implementation has to do, not what
 today's code does: attention over each row's real length (causal, GQA
@@ -48,9 +50,10 @@ def decode_flops(cfg, length: int) -> int:
 
 def paged_attention_work(cfg, lengths: Iterable[int], page_size: int,
                          kv_itemsize: int, q_itemsize: int = 2):
-    """(flops, bytes) of one paged decode-attention call, one layer:
-    each live row's grouped QK and PV over its length, the K and V pages
-    that length covers, and the row's q and output."""
+    """(flops, bytes) of one decode step's paged-attention calls, over
+    all layers: in each, every live row's grouped QK and PV over its
+    length, the K and V pages that length covers, and the row's q and
+    output."""
     H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     flops = 0
     nbytes = 0
@@ -61,13 +64,4 @@ def paged_attention_work(cfg, lengths: Iterable[int], page_size: int,
         pages = math.ceil(n / page_size)
         nbytes += 2 * pages * page_size * KV * D * kv_itemsize
         nbytes += 2 * H * D * q_itemsize
-    return flops, nbytes
-
-
-def roofline_share(flops: float, nbytes: float, seconds: float,
-                   peak_flops: float, peak_bw: float):
-    """(percent of the roofline reached, bound): the least time the chip
-    could take over the time taken."""
-    t_flops, t_bytes = flops / peak_flops, nbytes / peak_bw
-    bound = "compute" if t_flops >= t_bytes else "memory"
-    return 100.0 * max(t_flops, t_bytes) / seconds, bound
+    return flops * cfg.n_layers, nbytes * cfg.n_layers

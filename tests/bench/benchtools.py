@@ -20,9 +20,11 @@ DATA = Path(__file__).resolve().parent / "data"
 @pytest.fixture
 def cell_root(tmp_path):
     """A benchmark root made of the test cells' data files, with the
-    repository's metric readers and peaks table beside them."""
+    repository's metric readers, peaks table, dense reference and work
+    counts beside them."""
     root = tmp_path / "root"
     shutil.copytree(DATA / "root", root)
     shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics")
-    shutil.copy(REPO / "bench" / "peaks.json", root / "bench" / "peaks.json")
+    for name in ("peaks.json", "reference.py", "work.py"):
+        shutil.copy(REPO / "bench" / name, root / "bench" / name)
     return root

@@ -1,6 +1,7 @@
 """The trace reduction: busy and idle time, per-program device time and
 exposed collective time, on a hand-built trace in the form ``extract``
-gives and on a short extract recorded on the chip."""
+gives and on a short extract recorded on the chip; and the readings of
+``mfu`` and ``paged_attn_roofline`` on those traces, pinned."""
 
 from benchtools import REPO
 
@@ -8,7 +9,7 @@ import json
 
 import pytest
 
-from bench import readers, trace
+from bench import common, readers, trace
 
 MS = 1_000_000
 
@@ -94,3 +95,32 @@ def test_op_names_from_hlo_text():
     assert trace.op_name("all-reduce.5") == "all-reduce.5"
     assert trace.CONTAINER.match("while.5")
     assert not trace.CONTAINER.match("copy.5")
+
+
+# three decode steps and their prefills, as ``bench/serve.py`` records them
+STEPS = [{"rows": 3, "lengths": [300, 1201, 33], "prefills": [256]},
+         {"rows": 2, "lengths": [301, 1202], "prefills": []},
+         {"rows": 4, "lengths": [302, 1203, 35, 2048], "prefills": [1536, 32]}]
+
+
+@pytest.mark.parametrize("source,config,mfu,roofline", [
+    ("chip_trace_chat_v5e.json", "qwen1.5-0.5b",
+     7.97722495852485, 17.150152396053),
+    ("chip_trace_chat_spans_v5e.json", "qwen1.5-0.5b",
+     7.956027776649746, 18.109645086686175),
+    ("hand", "qwen3-4b", 23.803046127106597, 4.092999267399267)])
+def test_mfu_and_roofline_read_as_pinned(source, config, mfu, roofline):
+    # the readings of both cells' configurations through the work module
+    # their files name; pinned to the last digit, as they read when the
+    # readers counted with ``bench.work`` directly
+    ext = (_hand() if source == "hand" else json.loads(
+        (REPO / "tests" / "bench" / "data" / source).read_text()))
+    cfg = json.loads((REPO / "bench" / "configs" / f"{config}.json").read_text())
+    peaks = json.loads((REPO / "bench" / "peaks.json").read_text())
+    rec = {"trace": trace.reduce(ext), "traced": {"steps": [0, 3]},
+           "steps": STEPS, "model": common.model_config(cfg),
+           "work": common.load_module(REPO / cfg["work"]),
+           "page_size": 32, "kv_itemsize": 2,
+           "peaks": peaks["TPU v5 lite"], "chips": 1}
+    assert readers.mfu(rec) == mfu
+    assert readers.paged_attn_roofline(rec) == roofline

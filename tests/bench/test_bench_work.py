@@ -1,4 +1,5 @@
-"""bench/work.py against counts made by hand at published widths."""
+"""bench/work.py against counts made by hand at published widths, and
+the roofline share the readers take from such counts."""
 
 from benchtools import REPO  # noqa: F401  (puts the repository on sys.path)
 
@@ -6,7 +7,7 @@ import dataclasses
 
 import pytest
 
-from bench import work
+from bench import readers, work
 from repro.configs import get_config
 
 
@@ -35,17 +36,17 @@ def test_decode_and_prefill_flops_by_hand():
 
 
 def test_paged_attention_work_by_hand():
-    cfg = qwen3_stage()
+    cfg = qwen3_stage()                     # 8 layers, each attends fully
     flops, nbytes = work.paged_attention_work(cfg, [33, 0, 64], 32, 2)
-    assert flops == 4 * 40 * 128 * (33 + 64)
+    assert flops == 8 * 4 * 40 * 128 * (33 + 64)
     page = 32 * 8 * 128 * 2                 # one page of K (or V), bf16
     qo = 2 * 40 * 128 * 2                   # q and out of one row
-    assert nbytes == (2 * 2 * page + qo) + (2 * 2 * page + qo)
+    assert nbytes == 8 * ((2 * 2 * page + qo) + (2 * 2 * page + qo))
 
 
 @pytest.mark.parametrize("flops,nbytes,bound", [(197e12, 1.0, "compute"),
                                                 (1.0, 819e9, "memory")])
 def test_roofline_share(flops, nbytes, bound):
-    share, which = work.roofline_share(flops, nbytes, 2.0, 197e12, 819e9)
+    share, which = readers.roofline_share(flops, nbytes, 2.0, 197e12, 819e9)
     assert which == bound
     assert share == pytest.approx(50.0)
